@@ -14,7 +14,7 @@ phase (the sort's own Fig-1 argument):
     swept over chunk sizes.  derived = modelled HBM bytes ratio
     (reference streams the chunk once per tree level, fused touches it
     once: ratio = 1 + log2(leaves)).
-  * ``kernel_merge_*`` — the merge-path merge_split kernel (computes ONLY
+  * ``kernel_merge_*`` — the bitonic merge_split kernel (computes ONLY
     the kept half) vs merge-everything-discard-half.  derived = modelled
     HBM ratio 7/3 and merged-elems ratio 2.
 """
@@ -64,12 +64,13 @@ def bench_attention():
 
 
 def bench_sort():
-    # bitonic local sort (leaf kernel alone, the pre-fusion baseline)
+    # bitonic local sort of 1024-key rows (one leaf per row)
     xs = jax.random.randint(jax.random.key(4), (8, 1024), 0, 1 << 30,
                             dtype=jnp.int32)
-    t_bit = timeit(lambda: ops.bitonic_sort(xs), iters=1)
+    t_bit = timeit(lambda: ops.local_sort(xs), iters=1)
     t_ref = timeit(lambda: jax.jit(ref.sort_ref)(xs))
-    print(f"kernel_bitonic_sort_8x1024,{t_bit:.0f},interpret_mode=true")
+    print(f"kernel_bitonic_sort_8x1024,{t_bit:.0f},"
+          f"interpret_mode={jax.default_backend() != 'tpu'}")
     print(f"kernel_jnp_sort_8x1024,{t_ref:.0f},")
 
 
@@ -86,14 +87,14 @@ def bench_local(chunks: int, logcs, leaves: int):
         def reference(y):
             # today's engine reference path: Pallas leaf sort, then the
             # HBM-materialising Python merge-tree of vmapped rank merges
-            runs = ops.bitonic_sort(y.reshape(chunks * w, leaf))
+            runs = ops.local_sort(y.reshape(chunks * w, leaf))
             runs = runs.reshape(chunks, w, leaf)
             while runs.shape[1] > 1:
                 runs = jax.vmap(_merge_rows)(runs[:, 0::2], runs[:, 1::2])
             return runs.reshape(chunks, C)
 
         # interpret-mode wall clocks are noisy at small chunks: best-of-10
-        t_leaf = timeit(lambda: ops.bitonic_sort(x.reshape(chunks * w, leaf)),
+        t_leaf = timeit(lambda: ops.local_sort(x.reshape(chunks * w, leaf)),
                         iters=10)
         t_fused = timeit(lambda: ops.local_sort(x), iters=10)
         t_ref = timeit(lambda: reference(x), iters=10)
@@ -108,7 +109,7 @@ def bench_local(chunks: int, logcs, leaves: int):
 
 
 def bench_merge(chunks: int, logcs):
-    """merge-path merge_split (kept half only) vs merge-and-discard-half."""
+    """bitonic merge_split (kept half only) vs merge-and-discard-half."""
     keep = (jnp.arange(chunks) % 2) == 0
     for logc in logcs:
         C = 1 << logc

@@ -22,6 +22,7 @@ import argparse
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.core import Locale
 from benchmarks.common import timeit
@@ -33,7 +34,8 @@ def bench_pipeline(logb: int):
     from repro.data import SyntheticLM
 
     B = 1 << logb
-    mesh = (jax.make_mesh((len(jax.devices()),), ("data",))
+    mesh = (jax.make_mesh((len(jax.devices()),), ("data",),
+                          axis_types=(AxisType.Auto,))
             if len(jax.devices()) > 1 else None)
     cases = [("tokens", reduce_config(get_config("qwen3-0.6b")), 128),
              ("embeds", reduce_config(get_config("musicgen-medium")), 128)]

@@ -43,7 +43,7 @@ python -m repro.launch.homecheck --workload all --pods 2x2x2 \
 
 echo "== ci_gate: traced smoke serve + trace reconciliation =="
 TRACE="$(mktemp -t ci_trace.XXXXXX.jsonl)"
-python -m repro.launch.serve --policy homed --smoke --trace "$TRACE" \
+python -m repro.launch.serve --reduced --policy homed --smoke --trace "$TRACE" \
     > /dev/null || verdict=fail
 # the validator replays the trace and proves every counter identity
 # (charges == stats == summary bytes, pool refs balance, off-home decodes

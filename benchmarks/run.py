@@ -38,7 +38,7 @@ import re
 import subprocess
 import sys
 
-from benchmarks.common import run_with_devices
+from benchmarks.common import host_device_env, run_with_devices
 
 # (key, module, description): `key` names the run (a module may appear more
 # than once with different argv — the pods grid reuses bench_sort_cases)
@@ -58,7 +58,7 @@ MULTIDEV = [
 ]
 LOCAL = [
     ("bench_kernels", "Pallas kernel localisation (Fig 1, TPU-native)"),
-    ("bench_roofline", "dry-run roofline table (EXPERIMENTS.md)"),
+    ("bench_roofline", "dry-run roofline table (results/dryrun)"),
 ]
 
 # per-run argv for the full harness (8 devices)
@@ -135,8 +135,8 @@ def run_homecheck(key: str, smoke: bool, timeout: int = 600):
     for argv in CHECK_ARGS.get(key, []):
         for k, v in subst.items():
             argv = [a.replace(k, v) for a in argv]
-        env = dict(os.environ)
-        env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
+        # the CLI sets its own device count from --pods
+        env = host_device_env(None)
         r = subprocess.run(
             [sys.executable, "-m", "repro.launch.homecheck", *argv],
             capture_output=True, text=True, timeout=timeout, env=env)
@@ -253,6 +253,8 @@ def main(argv=None) -> None:
                          "every BENCH_*.json record (serve families also "
                          "get the R9 scheduler certificate)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     n_devices = 2 if args.smoke else 8
     records = []
 

@@ -73,19 +73,19 @@ def main():
         assert bool(jnp.all(y[1:] >= y[:-1]))
         print(f"hierarchical engine on 2x{n_dev // 2} emulated pods: ok")
 
-    # the kernels standalone: leaf-only bitonic, the fused local phase
-    # (non-power-of-two rows pad in VMEM scratch, never in HBM), and the
+    # the kernels standalone: the fused local sort on power-of-two and
+    # non-power-of-two rows (sentinels pad in VMEM, never in HBM), and the
     # kept-half-only merge split
     xs = jax.random.randint(jax.random.key(1), (8, 512), 0, 1 << 30,
                             dtype=jnp.int32)
-    ys = ops.bitonic_sort(xs)
+    ys = ops.local_sort(xs)
     assert bool(jnp.all(ys[:, 1:] >= ys[:, :-1]))
     zs = ops.local_sort(jax.random.randint(jax.random.key(3), (4, 384),
                                            0, 1 << 30, dtype=jnp.int32))
     assert bool(jnp.all(zs[:, 1:] >= zs[:, :-1]))
     lo = ops.merge_split(ys[:4], ys[4:], jnp.ones((4,), bool))
     assert bool(jnp.all(lo[:, 1:] >= lo[:, :-1]))
-    print("pallas kernels (bitonic / fused local_sort / merge_split): ok")
+    print("pallas kernels (fused local_sort / merge_split): ok")
 
 
 if __name__ == "__main__":

@@ -17,7 +17,7 @@ def main():
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--full", action="store_true")
-    ap.add_argument("--ckpt", default="/tmp/repro_train_lm")
+    ap.add_argument("--ckpt", default="runs/train_lm")
     args = ap.parse_args()
     cfg = get_config(args.arch)
     if not args.full:

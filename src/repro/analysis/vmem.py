@@ -4,9 +4,11 @@ Two fact extractors share one recursive jaxpr walker (through pjit,
 scan/while bodies, cond branches, shard_map, custom-derivative wrappers):
 
 * `pallas_footprints` — rule R3's view: the bytes each call keeps resident
-  per grid step (one block per operand/result BlockSpec plus every scratch
-  operand), compared against the per-core VMEM ceiling so oversized chunks
-  fail at lowering time instead of as a runtime crash at production sizes.
+  per grid step (one block per operand/result BlockSpec that the pipeline
+  stages in VMEM — operands left in HBM (`pl.ANY`) and moved by the
+  kernel's own DMAs count nothing — plus every scratch operand), compared
+  against the per-core VMEM ceiling so oversized chunks fail at lowering
+  time instead of as a runtime crash at production sizes.
 * `pallas_call_facts` — rules R5/R7/R8's view: the full grid, every
   operand's array/block shapes and a *callable* index map (the BlockSpec's
   `index_map_jaxpr` evaluated concretely per grid point), and the kernel
@@ -86,27 +88,25 @@ def pallas_footprints(jaxpr_like: Any) -> List[PallasFootprint]:
     return out
 
 
+def _in_hbm(bm) -> bool:
+    """Operand left in HBM for the kernel's own DMAs (never staged)."""
+    space = getattr(bm.transformed_block_aval, "memory_space", None)
+    return str(space) in ("any", "hbm")
+
+
 def _footprint(eqn) -> PallasFootprint:
     gm = eqn.params["grid_mapping"]
     blocks = []
     block_bytes = 0
     for bm in gm.block_mappings:
-        numel = _block_numel(bm.block_shape)
-        dtype = np.dtype(bm.array_shape_dtype.dtype)
-        block_bytes += numel * dtype.itemsize
+        dtype = np.dtype(bm.array_aval.dtype)
+        if not _in_hbm(bm):
+            block_bytes += _block_numel(bm.block_shape) * dtype.itemsize
         blocks.append((tuple(d if d is None else int(getattr(d, "block_size",
                                                              d))
                              for d in bm.block_shape), str(dtype)))
-    scratch_bytes = 0
-    n_scratch = getattr(gm, "num_scratch_operands", 0)
-    if n_scratch:
-        kernel_jaxpr = eqn.params.get("jaxpr")
-        if kernel_jaxpr is not None:
-            for var in kernel_jaxpr.invars[-n_scratch:]:
-                scratch_bytes += _aval_bytes(var.aval)
-    name = getattr(getattr(eqn.params.get("debug"), "func_name", None),
-                   "__str__", lambda: "")() or \
-        str(eqn.params.get("name", "")) or "pallas_call"
+    scratch_bytes = sum(_aval_bytes(a) for a in gm.scratch_avals)
+    name = str(eqn.params.get("name", "")) or "pallas_call"
     return PallasFootprint(name=name, grid=tuple(gm.grid),
                            block_bytes=block_bytes,
                            scratch_bytes=scratch_bytes,
@@ -165,7 +165,7 @@ def pallas_call_facts(jaxpr_like: Any) -> List[PallasCallFacts]:
                   and all(isinstance(g, (int, np.integer)) for g in grid))
         ops: List[OperandFacts] = []
         for k, bm in enumerate(gm.block_mappings):
-            sd = bm.array_shape_dtype
+            sd = bm.array_aval
             block = tuple(
                 None if d is None else int(getattr(d, "block_size", d))
                 for d in bm.block_shape)
@@ -175,8 +175,7 @@ def pallas_call_facts(jaxpr_like: Any) -> List[PallasCallFacts]:
                 dtype=str(np.dtype(sd.dtype)),
                 block_shape=block,
                 index_map=_index_map_fn(bm)))
-        nsi = eqn.params.get("name_and_src_info")
-        name = getattr(nsi, "name", None) or str(nsi or "") or "pallas_call"
+        name = str(eqn.params.get("name", "")) or "pallas_call"
         out.append(PallasCallFacts(
             name=name, grid=grid,
             inputs=tuple(o for o in ops if o.role == "in"),

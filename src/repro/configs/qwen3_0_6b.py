@@ -1,4 +1,10 @@
-"""qwen3-0.6b [dense] — qk_norm, GQA. [hf:Qwen/Qwen3-8B; hf]"""
+"""qwen3-0.6b [dense] — qk_norm, GQA. [hf:Qwen/Qwen3-0.6B; hf]
+
+Widths, depth and vocabulary as published.  The published model ties its
+LM head to the token embedding; this repo keeps an untied ``head_w``
+(`LM.init`), so it holds one more vocab x d_model matrix than the
+checkpoint would.
+"""
 from repro.configs.base import ArchConfig, ParallelConfig, register
 
 CONFIG = register(ArchConfig(

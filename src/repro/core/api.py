@@ -47,7 +47,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.homing import (Axis, Homing, check_divisible, logical_view,
@@ -143,7 +143,8 @@ class Locale:
         devices = list(jax.devices()) if devices is None else list(devices)
         if len(devices) <= 1:
             return cls(mesh=None, axis=axis, policy=policy)
-        mesh = jax.make_mesh((len(devices),), (axis,), devices=devices)
+        mesh = jax.make_mesh((len(devices),), (axis,), devices=devices,
+                             axis_types=(AxisType.Auto,))
         return cls(mesh=mesh, axis=axis, policy=policy)
 
     def with_policy(self, policy: LocalisationPolicy) -> "Locale":
@@ -327,8 +328,7 @@ class Locale:
 # ---------------------------------------------------------------------------
 @register_workload("sort")
 def _sort_workload(locale: Locale, *, backend: str = "constraint",
-                   num_workers=None, local_sort=None, interpret: bool = True,
-                   local_phase: str = None):
+                   num_workers=None, local_sort=None, local_phase: str = None):
     """The paper's validation app: distributed merge sort (Algorithms 1-3).
 
     A tuple locale axis (e.g. ("pod", "data")) selects the two-distance-class
@@ -337,14 +337,14 @@ def _sort_workload(locale: Locale, *, backend: str = "constraint",
 
     ``local_phase`` (engine backend) picks the per-device compute:
     "pallas" — the VMEM-resident production path (ONE fused kernel for leaf
-    sorts + local merge tree, merge-path merge-splits that compute only the
+    sorts + local merge tree, bitonic merge-splits that compute only the
     kept half); "reference" — the jnp oracle; None — auto by ``local_sort``.
     """
     from repro.core.sort import make_sort_fn
     axis = locale.axis if locale.mesh is not None else "data"
     return make_sort_fn(locale.mesh, locale.policy, num_workers=num_workers,
                         local_sort=local_sort, backend=backend, axis=axis,
-                        interpret=interpret, local_phase=local_phase)
+                        local_phase=local_phase)
 
 
 @register_workload("engine")

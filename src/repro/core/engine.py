@@ -11,7 +11,7 @@ literally, per device:
   2. the worker->core map is the mesh order, fixed at trace time (step 3 —
      the engine *is* the static mapping; `policy.static_mapping` has no
      runtime-chosen analogue here and is ignored);
-  3. the per-device local sort runs the Pallas `bitonic_sort` kernel inside
+  3. the per-device local sort runs the Pallas `local_sort` kernel inside
      each shard — the VMEM-resident `input_cpy` of Algorithm 2;
   4. the log2(m)-level merge tree exchanges runs with *explicit* collectives
      chosen by `LocalisationPolicy`:
@@ -66,7 +66,7 @@ implementations, selected by ``local_phase``:
                  fuses the leaf sorts and the whole local merge tree into
                  ONE pallas_call (chunk read from HBM once, written once),
                  and `kernels.merge_split` computes only the *kept* half of
-                 every compare-exchange (merge-path partitioning: C outputs
+                 every compare-exchange (a bitonic half-cleaner: C outputs
                  from 2C inputs, never materialising the discarded half).
   "reference"  — the jnp oracle: per-leaf Pallas sort, then a Python loop
                  of HBM-materialising vmapped rank merges, and
@@ -90,8 +90,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 from jax.sharding import PartitionSpec as P
 
 from repro.core.homing import Axis, Homing, axis_tuple
@@ -177,7 +176,7 @@ def _stride_axis(axes: Tuple[str, ...], sizes: Tuple[int, ...],
     raise ValueError(f"stride 2^{j} exceeds the {math.prod(sizes)}-device space")
 
 
-def _leaf_sort(rows, local_sort: LocalSort, interpret: bool):
+def _leaf_sort(rows, local_sort: LocalSort):
     """Sort each leaf row. rows: (k, leaf) -> (k, leaf) row-sorted.
 
     local_sort="bitonic" runs one kernel grid step per leaf, entirely in
@@ -190,7 +189,7 @@ def _leaf_sort(rows, local_sort: LocalSort, interpret: bool):
         return local_sort(rows, axis=-1)
     if local_sort != "bitonic":
         raise ValueError(f"unknown local_sort {local_sort!r}")
-    return _local_sort_kernel(rows, interpret=interpret)
+    return _local_sort_kernel(rows)
 
 
 def _merge_split(run, other, chunk: int, keep_low):
@@ -199,7 +198,7 @@ def _merge_split(run, other, chunk: int, keep_low):
     The reference form: merges the full 2*chunk run and discards half — 2x
     the merge compute and HBM traffic of the kept result.  The "pallas"
     local phase replaces it with `kernels.merge_split`, which computes only
-    the kept half (bit-exact, same rank arithmetic).
+    the kept half (the same values; equal keys in total order).
     """
     both = merge_sorted(run, other)                  # (2*chunk,)
     return jnp.where(keep_low, both[:chunk], both[chunk:])
@@ -371,7 +370,7 @@ def exchange_network(policy: LocalisationPolicy, sizes: Sequence[int],
 
 
 def _localised_shard(xloc, *, m: int, chunk: int, w_per_dev: int,
-                     hash_homed: bool, local_sort: LocalSort, interpret: bool,
+                     hash_homed: bool, local_sort: LocalSort,
                      axes: Tuple[str, ...], sizes: Tuple[int, ...],
                      net: "ExchangeNetwork", local_phase: str):
     """Per-device body, localised: one-shot relayout + merge-split tree."""
@@ -387,11 +386,10 @@ def _localised_shard(xloc, *, m: int, chunk: int, w_per_dev: int,
         # Algorithm 2 for the whole local phase: ONE pallas_call copies my
         # chunk into VMEM, runs the leaf stages AND the full local merge
         # tree on-chip, and writes the sorted run back once.
-        run = _local_sort_kernel(mine.reshape(1, chunk),
-                                 interpret=interpret)[0]
+        run = _local_sort_kernel(mine.reshape(1, chunk))[0]
     else:
         runs = _leaf_sort(mine.reshape(w_per_dev, chunk // w_per_dev),
-                          local_sort, interpret)
+                          local_sort)
         while runs.shape[0] > 1:          # merge my own leaves, no traffic
             runs = _merge_rows(runs[0::2], runs[1::2])
         run = runs[0]
@@ -421,9 +419,8 @@ def _localised_shard(xloc, *, m: int, chunk: int, w_per_dev: int,
                 partner = pods[np.asarray(rp.pod_partner)]
                 keep_low = jnp.asarray(np.asarray(rp.pod_keep_low))
                 if local_phase == "pallas":
-                    # batched merge-path replay: row q keeps only its half
-                    pods = _merge_split_kernel(pods, partner, keep_low,
-                                               interpret=interpret)
+                    # batched merge-split replay: row q keeps only its half
+                    pods = _merge_split_kernel(pods, partner, keep_low)
                 else:
                     merged = _merge_rows(pods, partner)  # (n_pods, 2*chunk)
                     pods = jnp.where(keep_low[:, None], merged[:, :chunk],
@@ -433,8 +430,8 @@ def _localised_shard(xloc, *, m: int, chunk: int, w_per_dev: int,
             other = jax.lax.ppermute(run, lv.axis, list(lv.perm))
             keep_low = jnp.asarray(np.asarray(lv.keep_low))[d]
             if local_phase == "pallas":
-                run = _merge_split_kernel(run[None], other[None], keep_low,
-                                          interpret=interpret)[0]
+                run = _merge_split_kernel(run[None], other[None],
+                                          keep_low)[0]
             else:
                 run = _merge_split(run, other, chunk, keep_low)
     return run
@@ -442,7 +439,7 @@ def _localised_shard(xloc, *, m: int, chunk: int, w_per_dev: int,
 
 def _unlocalised_shard(xloc, *, m: int, chunk: int, w: int,
                        hash_homed: bool, local_sort: LocalSort,
-                       interpret: bool, axes: Tuple[str, ...]):
+                       axes: Tuple[str, ...]):
     """Per-device body, non-localised: runs stay home-pinned between levels.
 
     Every level gathers the whole array (each worker's reads are remote —
@@ -473,7 +470,7 @@ def _unlocalised_shard(xloc, *, m: int, chunk: int, w: int,
 
     n_p = chunk * m
     full = gather(xloc)                           # leaves: remote read
-    runs = _leaf_sort(full.reshape(w, n_p // w), local_sort, interpret)
+    runs = _leaf_sort(full.reshape(w, n_p // w), local_sort)
     xloc = scatter(runs.reshape(-1))
     for _ in range(w.bit_length() - 1):
         full = gather(xloc)                       # per-level full exchange
@@ -486,8 +483,7 @@ def _unlocalised_shard(xloc, *, m: int, chunk: int, w: int,
 def shard_map_sort(x, mesh: Mesh,
                    policy: LocalisationPolicy = LocalisationPolicy(),
                    num_workers: Optional[int] = None,
-                   local_sort: LocalSort = "bitonic",
-                   interpret: bool = True, axis: Axis = AXIS,
+                   local_sort: LocalSort = "bitonic", axis: Axis = AXIS,
                    local_phase: Optional[str] = None):
     """Sort a 1-D array with the explicit shard_map engine (traceable).
 
@@ -529,19 +525,18 @@ def shard_map_sort(x, mesh: Mesh,
     if policy.localised:
         body = partial(_localised_shard, m=m, chunk=chunk,
                        w_per_dev=w_per_dev, hash_homed=hash_homed,
-                       local_sort=local_sort, interpret=interpret,
-                       axes=axes, sizes=sizes,
+                       local_sort=local_sort, axes=axes, sizes=sizes,
                        net=exchange_network(policy, sizes, axes),
                        local_phase=local_phase)
         out_spec = P(spec_axis)                    # chunk-contiguous output
     else:
         body = partial(_unlocalised_shard, m=m, chunk=chunk, w=w,
                        hash_homed=hash_homed, local_sort=local_sort,
-                       interpret=interpret, axes=axes)
+                       axes=axes)
         out_spec = in_spec                         # output stays home-pinned
 
-    y = shard_map(body, mesh=mesh, in_specs=in_spec, out_specs=out_spec,
-                  check_rep=False)(xin)
+    y = jax.shard_map(body, mesh=mesh, in_specs=in_spec, out_specs=out_spec,
+                      check_vma=False)(xin)
     if y.ndim == 2:                                # interleaved view -> logical
         y = y.reshape(-1)
     return y[:n]
@@ -687,15 +682,15 @@ def collective_census(n: int, sizes: Sequence[int],
 
 def make_engine_fn(mesh: Optional[Mesh], policy: LocalisationPolicy,
                    num_workers: Optional[int] = None,
-                   local_sort: LocalSort = "bitonic",
-                   interpret: bool = True, axis: Axis = AXIS,
+                   local_sort: LocalSort = "bitonic", axis: Axis = AXIS,
                    local_phase: Optional[str] = None):
     """Jitted engine sort for one Table-1 case; input donated (step 5)."""
     from repro.core.sort import sort_entry          # local: avoid cycle
     resolve_local_phase(local_phase, local_sort)    # fail fast, not at trace
     if mesh is None:
         a = axis if isinstance(axis, str) else axis[-1]
-        mesh = jax.make_mesh((len(jax.devices()),), (a,))
+        mesh = jax.make_mesh((len(jax.devices()),), (a,),
+                             axis_types=(AxisType.Auto,))
         axis = a
     axes = axis_tuple(axis)
     m = math.prod(_axes_sizes(mesh, axes))
@@ -703,7 +698,7 @@ def make_engine_fn(mesh: Optional[Mesh], policy: LocalisationPolicy,
     granule = engine_granule(m, num_workers, hash_homed)
     fn = partial(shard_map_sort, mesh=mesh, policy=policy,
                  num_workers=num_workers, local_sort=local_sort,
-                 interpret=interpret, axis=axis, local_phase=local_phase)
+                 axis=axis, local_phase=local_phase)
     entry = sort_entry(jax.jit(fn, donate_argnums=(0,)), granule)
     sizes = _axes_sizes(mesh, axes)
 

@@ -237,8 +237,7 @@ def distributed_merge_sort(x, mesh: Optional[Mesh] = None,
 
 def make_sort_fn(mesh, policy: LocalisationPolicy, num_workers=None,
                  local_sort=None, backend: str = "constraint",
-                 axis: Axis = "data", interpret: bool = True,
-                 local_phase: str = None):
+                 axis: Axis = "data", local_phase: str = None):
     """Jitted sort for one Table-1 case; input buffer donated (step 5).
 
     backend="constraint": the original `with_sharding_constraint`-hint tree —
@@ -263,8 +262,7 @@ def make_sort_fn(mesh, policy: LocalisationPolicy, num_workers=None,
         from repro.core.engine import make_engine_fn   # local: avoid cycle
         return make_engine_fn(mesh, policy, num_workers=num_workers,
                               local_sort=local_sort or "bitonic",
-                              axis=axis, interpret=interpret,
-                              local_phase=local_phase)
+                              axis=axis, local_phase=local_phase)
     if local_phase not in (None, "reference"):
         raise ValueError(
             f"local_phase={local_phase!r} needs backend='shard_map' — the "

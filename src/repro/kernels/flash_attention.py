@@ -1,6 +1,6 @@
 """Flash attention Pallas TPU kernel: blocked online softmax with VMEM tiling.
 
-This is KV-localisation at the cache level (DESIGN.md §2): each (query tile,
+This is KV-localisation at the cache level: each (query tile,
 KV tile) pair is copied HBM->VMEM once via the BlockSpec index maps, all
 arithmetic runs on the MXU out of VMEM, and only the finished output tile is
 written back. Supports causal masking, sliding windows (with *block
@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -77,7 +79,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    scale: float | None = None, interpret: bool = True):
+                    scale: float | None = None,
+                    interpret: bool | None = None):
     """q: (B, H, Sq, hd); k, v: (B, KV, Skv, hd) -> (B, H, Sq, hd)."""
     B, H, Sq, hd = q.shape
     KV, Skv = k.shape[1], k.shape[2]
@@ -110,6 +113,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
     return out[:, :, :Sq]

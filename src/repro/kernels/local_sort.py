@@ -6,74 +6,87 @@ tree level materialises the full chunk to HBM and reads it back.  The paper's
 Algorithm 2 keeps each worker's `input_cpy` cache-resident for the *entire*
 local phase, not just the leaves; this kernel is that discipline for real:
 
-  * one `pallas_call` per chunk: the chunk is copied HBM->VMEM once,
+  * one grid step per chunk: the chunk is copied HBM->VMEM once (one DMA
+    into a single VMEM buffer),
   * the bitonic leaf stages AND all log2(#leaves) merge-tree levels run
     on-chip (the merge levels are the high-`k` stages of the same bitonic
     network — a bitonic merge of two sorted leaves is exactly stage 2*leaf),
-  * the fully sorted run is written back once.
+  * the fully sorted run is copied back once.
 
 HBM traffic: 2*chunk*itemsize total, vs 2*chunk*itemsize*(1 + log2(w)) for
 the reference tree — the Fig-1 amortisation argument applied to the sort's
 own local phase.
 
-Sentinel padding is folded into the kernel: a non-power-of-two row is
-extended to the next power of two with BIG sentinels *in VMEM scratch*
-(never materialised to HBM), sorted, and the real prefix written back.
-This replaces the engine's old `_leaf_sort` padding, which concatenated a
-sentinel tail in HBM on every call (up to 2x wasted traffic for leaf sizes
-just above a power of two).
+Sentinel padding is folded into the kernel: a chunk whose length is not a
+power of two (of at least one tile) is extended to one with `KEY_MAX`
+sentinels as the network's first pass loads each register block — the tail
+rows are never copied from HBM, and the mask is written with full aligned
+tiles.  Only a chunk that is not a whole number of 128-key rows is padded
+in HBM first (small chunks, never the engine's power-of-two ones).
 
-VMEM budget per grid step: next_pow2(chunk) * itemsize for the scratch run
-plus the compare-exchange temporaries (~4x that with the partner/min/max
-views), e.g. a 64 KiB int32 chunk needs ~0.3 MiB — comfortably inside the
-~16 MiB/core budget up to chunks of ~1M elements.
+VMEM: one buffer of `padded_len(C)` int32 keys per grid step — the
+inputs and outputs stay in HBM (`pl.ANY`) and move by DMA.  `max_chunk`
+is the largest chunk that fits `VMEM_BYTES_PER_CORE`.
 """
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.sort import pad_value
-from repro.kernels.bitonic_sort import bitonic_stages
+from repro.kernels import (VMEM_BYTES_PER_CORE, VMEM_HEADROOM,
+                           resolve_interpret, vmem_bytes)
+from repro.kernels.bitonic_sort import (KEY_MAX, LANES, from_keys, padded_len,
+                                        sort_network, to_keys)
 
 
-def _kernel(x_ref, o_ref):
-    o_ref[...] = bitonic_stages(x_ref[...])
+def max_chunk() -> int:
+    """Largest power-of-two chunk of 32-bit keys one local sort holds."""
+    return 1 << ((VMEM_BYTES_PER_CORE - VMEM_HEADROOM) // 4).bit_length() - 1
 
 
-def _kernel_padded(x_ref, o_ref, scratch_ref, *, C: int):
-    # the one HBM->VMEM copy; the sentinel tail lives only in scratch
-    scratch_ref[...] = jnp.full(scratch_ref.shape,
-                                pad_value(x_ref.dtype), x_ref.dtype)
-    scratch_ref[:, :C] = x_ref[...]
-    o_ref[...] = bitonic_stages(scratch_ref[...])[:, :C]
+def _kernel(x_hbm, o_hbm, w, *, C: int):
+    i = pl.program_id(0)
+    rows_in = x_hbm.shape[1]
+    data = w if rows_in == w.shape[0] else w.at[pl.ds(0, rows_in)]
+    pltpu.sync_copy(x_hbm.at[i], data)
+    prologue = None
+    if C < w.shape[0] * LANES:
+        def prologue(v, idx):
+            return jnp.where(idx < C, v, KEY_MAX)
+    sort_network(w, prologue)
+    pltpu.sync_copy(data, o_hbm.at[i])
 
 
-def local_sort(x, *, interpret: bool = True):
+def local_sort(x, *, interpret: Optional[bool] = None):
     """Sort each row of x: (rows, C) -> (rows, C), any C >= 1.
 
     One grid step per row; the whole row (a device chunk: its leaves and the
     full local merge tree) stays in VMEM between the single read and the
-    single write-back.  Non-power-of-two C is handled with in-VMEM sentinel
-    padding (see module docstring) — callers never pre-pad.
+    single write-back.  int32 and float32 keys; floats sort in total order
+    (``-0.0`` before ``+0.0``), bit-exact as a permutation of the input.
     """
     rows, C = x.shape
-    L = 1 << max(0, (C - 1).bit_length())
-    if L == C:
-        kernel, scratch = _kernel, []
-    else:
-        kernel = partial(_kernel_padded, C=C)
-        scratch = [pltpu.VMEM((1, L), x.dtype)]
-    return pl.pallas_call(
-        kernel,
+    L = padded_len(C)
+    keys = to_keys(x)
+    Cr = -(-C // LANES) * LANES
+    if Cr != C:
+        keys = jnp.pad(keys, ((0, 0), (0, Cr - C)), constant_values=KEY_MAX)
+    keys = keys.reshape(rows, Cr // LANES, LANES)
+    out = pl.pallas_call(
+        partial(_kernel, C=C),
         grid=(rows,),
-        in_specs=[pl.BlockSpec((1, C), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, C), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, C), x.dtype),
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(x)
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct(keys.shape, jnp.int32),
+        scratch_shapes=[pltpu.VMEM((L // LANES, LANES), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_bytes(L * 4)),
+        interpret=resolve_interpret(interpret),
+        name="local_sort",
+    )(keys)
+    return from_keys(out.reshape(rows, Cr)[:, :C], x.dtype)

@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _kernel(x_ref, o_ref, *, reps: int):
     y = x_ref[...].astype(jnp.float32)
@@ -26,7 +28,7 @@ def _kernel(x_ref, o_ref, *, reps: int):
 
 
 def localised_copy(x, reps: int, *, block: int | None = None,
-                   interpret: bool = True):
+                   interpret: bool | None = None):
     """x: (chunks, block_len) -> same shape; R passes per chunk in VMEM."""
     chunks, L = x.shape
     bl = block or L
@@ -36,5 +38,5 @@ def localised_copy(x, reps: int, *, block: int | None = None,
         in_specs=[pl.BlockSpec((1, bl), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((1, bl), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((chunks, L), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x)

@@ -1,4 +1,4 @@
-"""Merge-path merge-split kernel: compute only the half you keep.
+"""Bitonic merge-split kernel: compute only the half you keep.
 
 The engine's block bitonic network exchanges full chunks with a partner and
 keeps either the low or the high half of the merged 2C run.  The reference
@@ -6,47 +6,96 @@ keeps either the low or the high half of the merged 2C run.  The reference
 HBM) and then discards half — 2x the merge compute and >2x the HBM traffic
 of what the result actually needs.
 
-This kernel partitions the merge by output rank instead (the merge-path /
-PCOT "work proportional to what you keep" discipline): the kept half is the
-contiguous output window ``k in [0, C)`` (keep-low) or ``k in [C, 2C)``
-(keep-high) of the stable rank merge, so it evaluates the gather-form merge
-only at those C ranks.  Per row it reads the two C-element runs once, does
-O(C log C) rank comparisons (two searchsorted passes — the binary-search
-form of the merge-path diagonal), and writes exactly C elements: O(C)
-memory, no 2C intermediate, and bit-exact against
-``merge_sorted(a, b)[:C]`` / ``[C:]`` including duplicate/sentinel ties
-(same ``side="left"`` rank arithmetic as `repro.core.sort.merge_sorted`).
+This kernel never forms the 2C run.  For sorted ``a`` and ``b``, the C
+elementwise minima of ``a[i]`` and ``b[C-1-i]`` are exactly the low half of
+the merge and the C maxima the high half, each a bitonic sequence (Batcher's
+half-cleaner).  So per row it DMAs the two runs into VMEM, makes one pass
+that keeps the min or the max against ``b`` read back to front (tiles in
+reverse order, each reversed in registers by XOR-partner rotations — Mosaic
+has no ``rev``), then sorts the kept bitonic half with the last stage of the
+bitonic network: log2(C) half-cleaner substages.  O(C log C) on-chip work,
+2C reads and C writes of HBM, no 2C intermediate.
 
-The batched form is the hierarchical engine's cross-pod replay unit: row r
-merges pod r's chunk with its partner pod's chunk under its own keep flag.
+The result equals ``merge_sorted(a, b)[:C]`` / ``[C:]`` as values, ties and
+sentinels included; keys that compare equal but differ in bits (``-0.0`` and
+``+0.0``) come out in total order rather than merge order.
+
+The keep flag is a per-row scalar read from SMEM.  The batched form is the
+hierarchical engine's cross-pod replay unit: row r merges pod r's chunk with
+its partner pod's chunk under its own keep flag.
 """
 from __future__ import annotations
+
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret, vmem_bytes
+from repro.kernels.bitonic_sort import (KEY_MAX, KEY_MIN, LANES, SUBLANES,
+                                        TILE, from_keys, merge_stage,
+                                        padded_len, to_keys)
 
 
-def _kernel(a_ref, b_ref, keep_ref, o_ref):
-    a = a_ref[0, :]
-    b = b_ref[0, :]
-    C = a.shape[0]
-    # output ranks of the kept half: the merge-path window [0,C) or [C,2C)
-    k = jnp.arange(C) + jnp.where(keep_ref[0, 0] != 0, 0, C)
-    # stable rank merge, gather form, evaluated only at the kept ranks —
-    # identical arithmetic to merge_sorted (a-elements win ties, side="left")
-    ia = jnp.arange(C) + jnp.searchsorted(b, a, side="left")
-    ra = jnp.searchsorted(ia, k, side="left")
-    ra_c = jnp.minimum(ra, C - 1)
-    is_a = (ra < C) & (jnp.take(ia, ra_c) == k)
-    rb = jnp.clip(k - ra, 0, C - 1)
-    o_ref[0, :] = jnp.where(is_a, jnp.take(a, ra_c), jnp.take(b, rb))
+def _reverse_tile(v):
+    """Reverse the TILE keys of one (8, 128) register: i -> i ^ (TILE - 1)."""
+    idx = (jax.lax.broadcasted_iota(jnp.int32, v.shape, 0) * LANES
+           + jax.lax.broadcasted_iota(jnp.int32, v.shape, 1))
+    j = 1
+    while j < TILE:
+        axis, s, n = (1, j, LANES) if j < LANES else (0, j // LANES, SUBLANES)
+        v = jnp.where((idx & j) == 0, pltpu.roll(v, n - s, axis),
+                      pltpu.roll(v, s, axis))
+        j *= 2
+    return v
 
 
-def merge_split(a, b, keep_low, *, interpret: bool = True):
+def _kernel(keep_ref, a_hbm, b_hbm, o_hbm, w, bw):
+    i = pl.program_id(0)
+    pltpu.sync_copy(a_hbm.at[i], w)
+    pltpu.sync_copy(b_hbm.at[i], bw)
+    keep_low = keep_ref[i] != 0
+    tiles = w.shape[0] // SUBLANES
+
+    def half_clean(t, carry):
+        r = pl.multiple_of(t * SUBLANES, SUBLANES)
+        rb = pl.multiple_of((tiles - 1 - t) * SUBLANES, SUBLANES)
+        a = w[pl.ds(r, SUBLANES), :]
+        b = _reverse_tile(bw[pl.ds(rb, SUBLANES), :])
+        w[pl.ds(r, SUBLANES), :] = jnp.where(keep_low, jnp.minimum(a, b),
+                                             jnp.maximum(a, b))
+        return carry
+
+    jax.lax.fori_loop(0, tiles, half_clean, 0)
+    merge_stage(w, w.shape[0] * LANES)
+    pltpu.sync_copy(w, o_hbm.at[i])
+
+
+def _merge_split_keys(a, b, keep, interpret: bool):
+    rows, L = a.shape
+    shape = (rows, L // LANES, LANES)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        _kernel,
+        grid=(rows,),
+        in_specs=[smem, hbm, hbm],
+        out_specs=hbm,
+        out_shape=jax.ShapeDtypeStruct(shape, jnp.int32),
+        scratch_shapes=[pltpu.VMEM(shape[1:], jnp.int32)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_bytes(2 * L * 4)),
+        interpret=interpret,
+        name="merge_split",
+    )(keep, a.reshape(shape), b.reshape(shape)).reshape(rows, L)
+
+
+def merge_split(a, b, keep_low, *, interpret: Optional[bool] = None):
     """Row-wise merge-split. a, b: (rows, C) sorted rows; keep_low: per-row
     (or scalar, broadcast) flag — True keeps the low half of the merged 2C
-    run, False the high half.  Returns (rows, C); bit-exact against
+    run, False the high half.  Returns (rows, C), equal to
     ``merge_sorted(a[r], b[r])[:C]`` / ``[C:]``.
     """
     rows, C = a.shape
@@ -59,14 +108,23 @@ def merge_split(a, b, keep_low, *, interpret: bool = True):
             f"keep_low must be a scalar or a length-{rows} vector of "
             f"per-row flags (one per merge-split row); got shape "
             f"{jnp.shape(keep_low)} for a/b of shape {(rows, C)}")
-    keep = jnp.broadcast_to(keep.astype(jnp.int32)[:, None], (rows, 1))
-    return pl.pallas_call(
-        _kernel,
-        grid=(rows,),
-        in_specs=[pl.BlockSpec((1, C), lambda i: (i, 0)),
-                  pl.BlockSpec((1, C), lambda i: (i, 0)),
-                  pl.BlockSpec((1, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, C), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, C), a.dtype),
-        interpret=interpret,
-    )(a, b, keep)
+    keep = jnp.broadcast_to(keep, (rows,))
+    ka, kb = to_keys(a), to_keys(b)
+    L = padded_len(C)
+    pad = L - C
+    if pad:
+        # a low half survives sentinels appended past the runs, a high half
+        # sentinels placed before them; both keep the rows sorted
+        k = keep[:, None]
+
+        def fit(v):
+            return jnp.where(k, jnp.pad(v, ((0, 0), (0, pad)),
+                                        constant_values=KEY_MAX),
+                             jnp.pad(v, ((0, 0), (pad, 0)),
+                                     constant_values=KEY_MIN))
+        ka, kb = fit(ka), fit(kb)
+    out = _merge_split_keys(ka, kb, keep.astype(jnp.int32),
+                            resolve_interpret(interpret))
+    if pad:
+        out = jnp.where(keep[:, None], out[:, :C], out[:, pad:])
+    return from_keys(out, a.dtype)

@@ -96,10 +96,12 @@ def main(argv=None) -> int:
     ap.add_argument("--verbose", action="store_true")
     args = ap.parse_args(argv)
 
-    # the mesh is emulated out of host devices: the flag must be set before
-    # jax (transitively, any repro module) first touches the backend.
+    # the mesh is emulated out of host devices: the flags must be set before
+    # jax (transitively, any repro module) first touches the backend, and
+    # the CPU platform keeps this process off any accelerator
     if args.pods is not None:
         n_dev = args.pods[0] * args.pods[1] * args.pods[2]
+        os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={n_dev}").strip()

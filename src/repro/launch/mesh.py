@@ -17,12 +17,13 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(n_data: int | None = None, n_model: int = 1,
@@ -57,4 +58,4 @@ def make_host_mesh(n_data: int | None = None, n_model: int = 1,
             f"requested mesh shape {dict(zip(axes, shape))} needs {want} "
             f"device(s) but this host has {n} — the shape must use exactly "
             f"the available devices")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

@@ -1,8 +1,13 @@
 """Serving launcher: batched decode server over a (restored) checkpoint.
 
     PYTHONPATH=src python -m repro.launch.serve --arch granite-3-2b \
-        [--ckpt DIR] [--requests 8] [--slots 4] \
+        [--reduced] [--ckpt DIR] [--requests 8] [--slots 4] \
         [--policy fifo|homed] [--pods PxD[xM]]
+
+The configuration is served as published (its widths and depth, bf16),
+with parameters from ``LM.init`` at `PARAM_SEED` unless ``--ckpt`` holds
+a checkpoint.  ``--reduced`` serves the 4-layer float32 smoke variant of
+the same family (`reduce_config`) — what the CPU tests and the CI gate run.
 
 ``--policy`` selects the serving scheduler (`repro.runtime.scheduler`):
 ``fifo`` is the arrival-order oracle, ``homed`` routes/batches/evicts by
@@ -27,7 +32,8 @@ import jax
 
 from repro.checkpoint import latest_step, restore
 from repro.configs import get_config, reduce_config
-from repro.configs.base import ShapeSpec
+from repro.configs.base import ArchConfig, ShapeSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import LM
 from repro.obs import Tracer, set_tracer
 from repro.obs import metrics as obs_metrics
@@ -43,6 +49,36 @@ def parse_pods(spec: str):
         raise argparse.ArgumentTypeError(
             f"--pods wants PxD or PxDxM with positive ints, got {spec!r}")
     return tuple(parts)
+
+
+#: decode cache length of every served slot
+MAX_LEN = 96
+#: seed of the parameters `LM.init` draws when no checkpoint is restored
+PARAM_SEED = 0
+
+
+def serve_config(arch: str, reduced: bool) -> ArchConfig:
+    """The configuration as published, or its 4-layer smoke variant."""
+    cfg = get_config(arch)
+    return reduce_config(cfg, layers=4) if reduced else cfg
+
+
+def synthetic_requests(cfg: ArchConfig, n: int, *, slots: int, max_new: int,
+                       sessions: int):
+    """The launcher's request stream: prompts of 2-8 tokens drawn from the
+    vocabulary, ``max_new`` or half of it new tokens, ``sessions`` affinity
+    keys, ``slots`` arrivals per step."""
+    rng = np.random.RandomState(0)
+    out = []
+    for rid in range(n):
+        plen = rng.randint(2, 9)
+        out.append(Request(
+            rid=rid,
+            prompt=rng.randint(0, cfg.vocab_size, plen).astype(np.int32),
+            max_new=int(rng.choice([max_new // 2 or 1, max_new])),
+            session=f"s{rng.randint(sessions)}",
+            t_arrive=float(rid // max(1, slots))))
+    return out
 
 
 def build_plan(pods, slots: int, max_len: int, cfg):
@@ -63,6 +99,9 @@ def build_plan(pods, slots: int, max_len: int, cfg):
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the 4-layer float32 smoke variant of --arch "
+                    "instead of the published configuration")
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
@@ -90,31 +129,27 @@ def main(argv=None):
     if args.smoke:
         args.requests, args.slots, args.max_new = 8, 4, 4
 
-    cfg = reduce_config(get_config(args.arch), layers=4)
+    enable_compile_cache()
+    cfg = serve_config(args.arch, args.reduced)
     model = LM(cfg)
-    params = model.init(jax.random.key(0))
+    params = model.init(jax.random.key(PARAM_SEED))
     if args.ckpt and latest_step(args.ckpt) is not None:
         params = restore(args.ckpt, latest_step(args.ckpt),
                          {"params": params})["params"]
-    plan = build_plan(args.pods, args.slots, 96, cfg)
+    plan = build_plan(args.pods, args.slots, MAX_LEN, cfg)
     tracer = None
     if args.trace:
         tracer = Tracer(args.trace, tool="launch.serve", arch=args.arch,
                         policy=args.policy, slots=args.slots,
                         pods=args.pods, requests=args.requests)
         set_tracer(tracer)     # engine-level spans join the same stream
-    srv = DecodeServer(cfg, params, batch_slots=args.slots, max_len=96,
+    srv = DecodeServer(cfg, params, batch_slots=args.slots, max_len=MAX_LEN,
                        plan=plan, scheduler=args.policy,
                        prompt_pad=args.prompt_pad or None, tracer=tracer)
-    rng = np.random.RandomState(0)
-    for rid in range(args.requests):
-        plen = rng.randint(2, 9)
-        srv.submit(Request(
-            rid=rid,
-            prompt=rng.randint(0, cfg.vocab_size, plen).astype(np.int32),
-            max_new=int(rng.choice([args.max_new // 2 or 1, args.max_new])),
-            session=f"s{rng.randint(args.sessions)}",
-            t_arrive=float(rid // max(1, args.slots))))
+    for req in synthetic_requests(cfg, args.requests, slots=args.slots,
+                                  max_new=args.max_new,
+                                  sessions=args.sessions):
+        srv.submit(req)
     for r in sorted(srv.run(), key=lambda r: r.rid):
         print(f"req {r.rid} (session {r.session}, home {r.home}, "
               f"wait {r.wait:.0f}): -> {r.out}")

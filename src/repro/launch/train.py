@@ -13,9 +13,8 @@ from __future__ import annotations
 import argparse
 import sys
 
-import jax
-
 from repro.configs import get_config, reduce_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.configs.base import SHAPES
 from repro.runtime.trainer import Trainer, TrainerConfig
@@ -28,7 +27,7 @@ def main():
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--global-batch", type=int, default=8)
     ap.add_argument("--seq-len", type=int, default=64)
-    ap.add_argument("--ckpt", default="/tmp/repro_launch_train")
+    ap.add_argument("--ckpt", default="runs/launch_train")
     ap.add_argument("--production", action="store_true",
                     help="full config on the production mesh (TPU pods)")
     ap.add_argument("--multipod", action="store_true")
@@ -43,6 +42,7 @@ def main():
         print("\n".join(out["stdout"][-5:]))
         sys.exit(0 if out["ok"] else 1)
 
+    enable_compile_cache()
     if args.production:
         cfg = get_config(args.arch)
         mesh = make_production_mesh(multi_pod=args.multipod)

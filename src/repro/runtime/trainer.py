@@ -42,7 +42,7 @@ class TrainerConfig:
     steps: int = 100
     global_batch: int = 8
     seq_len: int = 64
-    ckpt_dir: str = "/tmp/repro_ckpt"
+    ckpt_dir: str = "runs/ckpt"            # inside the checkout (gitignored)
     ckpt_every: int = 20
     log_every: int = 10
     seed: int = 0

@@ -82,9 +82,10 @@ def test_sort_multidevice_subprocess():
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType
 from repro.core import LocalisationPolicy, Homing, distributed_merge_sort
 from repro.core.microbench import repetitive_copy, reference
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 x = jax.random.randint(jax.random.key(1), (1 << 14,), 0, 1 << 30, jnp.int32)
 expect = np.sort(np.asarray(x))
 for loc in [True, False]:

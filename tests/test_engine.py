@@ -11,6 +11,7 @@ import sys
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 import numpy as np
 import pytest
 
@@ -72,7 +73,8 @@ ENGINE_SINGLE = [pytest.param(p, n, dt, marks=() if i in (0, 3)
                          ids=lambda v: getattr(v, "name", v))
 def test_engine_single_device_bit_exact(policy, dtype, n):
     """1-device mesh: leaves + local merge path, Pallas bitonic local sort."""
-    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    mesh = jax.make_mesh((len(jax.devices()),), ("data",),
+                         axis_types=(AxisType.Auto,))
     x = _rand(n, jnp.dtype(dtype))
     expect = np.sort(np.asarray(x))
     fn = Locale(mesh=mesh, policy=policy).workload("engine", num_workers=8)
@@ -185,7 +187,8 @@ def test_sort_rejects_nan_floats_eagerly(backend):
 
 
 def test_put_pad_rejects_nan():
-    loc = Locale(mesh=jax.make_mesh((1,), ("data",)))
+    loc = Locale(mesh=jax.make_mesh((1,), ("data",),
+                                    axis_types=(AxisType.Auto,)))
     # axis_size 1 never pads -> accepted; explicit pad granule via the sort
     h = loc.put(jnp.asarray([jnp.nan, 1.0], jnp.float32), pad=True)
     assert h.size == 2
@@ -229,7 +232,7 @@ def test_hierarchical_policy_factory():
 
 def test_hierarchical_policy_needs_pod_axis():
     """A hierarchical policy on a flat single-axis locale is an error."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     fn = Locale(mesh=mesh,
                 policy=LocalisationPolicy.hierarchical()).workload(
                     "engine", num_workers=4)

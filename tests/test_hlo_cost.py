@@ -53,9 +53,9 @@ def test_collectives_inside_loops_are_scaled():
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.launch.hlo_cost import analyze
-mesh = jax.make_mesh((4,), ("d",))
+mesh = jax.make_mesh((4,), ("d",), axis_types=(AxisType.Auto,))
 x = jnp.zeros((8, 64), jnp.float32)
 
 def f(x):

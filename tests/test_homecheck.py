@@ -58,7 +58,7 @@ def test_report_clean_errors_suppress_and_summarize():
 # R3 fixture: an oversized local_sort chunk cannot fit per-core VMEM
 # ---------------------------------------------------------------------------
 def test_r3_vmem_budget_flags_oversized_local_sort_chunk():
-    big = jax.ShapeDtypeStruct((1, 1 << 23), jnp.float32)   # 32 MiB row
+    big = jax.ShapeDtypeStruct((1, 1 << 25), jnp.float32)   # 128 MiB row
     jx = jax.make_jaxpr(lambda v: local_sort(v))(big)       # trace only
     rep = Report(target="r3-fixture")
     r3_vmem_budget(rep, pallas_footprints(jx), VMEM_BYTES_PER_CORE)
@@ -105,17 +105,18 @@ from repro.core import Homing, Locale, LocalisationPolicy, collective_census
 from repro.core.engine import engine_granule
 from repro.launch.mesh import make_host_mesh
 
-# R2: the PR 3 GSPMD miscompile class, kept as a fixture.  An in-jit
-# sentinel concatenate + chunked constraint on a mesh with a >1 unrelated
-# "model" axis makes GSPMD insert an all-reduce spanning ALL axes — padded
-# elements arrive summed across "model".  homecheck must flag it.
+# R2: values homed across a mesh axis the locale never declared.  The old
+# miscompile (GSPMD summing padded elements across an unrelated "model"
+# axis) no longer reproduces on this XLA, so the fixture homes the keys
+# over ("pod", "data", "model") itself: the sort's gather then spans
+# "model", and homecheck must flag it.
 mesh = make_host_mesh(n_pods=2, n_data=2, n_model=2)
 
 def leaky(x):
     pad = jnp.full((31,), jnp.iinfo(jnp.int32).max, jnp.int32)
     y = jnp.concatenate([x, pad])
     y = jax.lax.with_sharding_constraint(
-        y, NamedSharding(mesh, P(("pod", "data"))))
+        y, NamedSharding(mesh, P(("pod", "data", "model"))))
     return jnp.sort(y)
 
 hlo = jax.jit(leaky).lower(jnp.zeros((4065,), jnp.int32)).compile().as_text()

@@ -59,7 +59,7 @@ def test_flash_attention_block_skipping_matches_dense_window():
 
 
 # ---------------------------------------------------------------------------
-# bitonic sort
+# bitonic local sort on power-of-two rows
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("chunks,L", [(1, 64), (4, 128), (8, 256),
                                       pytest.param(2, 1024,
@@ -71,7 +71,7 @@ def test_bitonic_sort_vs_ref(chunks, L, dtype):
                                dtype=jnp.int32)
     else:
         x = jax.random.normal(jax.random.key(0), (chunks, L), jnp.float32)
-    out = ops.bitonic_sort(x)
+    out = ops.local_sort(x)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref.sort_ref(x)))
 
 

@@ -1,4 +1,4 @@
-"""VMEM-resident local phase: fused `local_sort` + merge-path `merge_split`.
+"""VMEM-resident local phase: fused `local_sort` + bitonic `merge_split`.
 
 Property grid pins the two kernels bit-exact against their jnp oracles
 (`jnp.sort` rows; `merge_sorted`-then-slice) across duplicates, BIG/inf
@@ -6,8 +6,8 @@ sentinel values appearing as *data*, already/reverse-sorted inputs, both
 core dtypes and non-power-of-two lengths/leaf counts (the in-VMEM sentinel
 padding path).  The engine is then pinned bit-exact under both
 ``local_phase`` implementations, fast on the 1-device mesh and (slow) on
-8-device flat + emulated-pod meshes.  Compiled (interpret=False) variants
-are skip-guarded: they only run on a real accelerator.
+8-device flat + emulated-pod meshes.  On the CPU the kernels run in the
+Pallas interpreter; `test_chip_compile.py` lowers them for a TPU v5e.
 """
 import os
 import subprocess
@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.core import (LOCAL_PHASES, Homing, Locale, LocalisationPolicy,
                         exchange_schedule)
@@ -26,7 +27,6 @@ from repro.kernels import ops
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:                 # for the in-process benchmark tests
     sys.path.insert(0, ROOT)
-ON_CPU = jax.default_backend() == "cpu"
 BIGI = int(jnp.iinfo(jnp.int32).max)
 
 
@@ -46,6 +46,9 @@ def _rows(name: str, C: int, rows: int = 3):
     if name == "sentinel_float":         # +/-inf present as real data
         x = jax.random.normal(key, (rows, C), jnp.float32)
         return x.at[:, ::5].set(jnp.inf).at[:, 1::7].set(-jnp.inf)
+    if name == "signed_zeros":           # -0.0 and +0.0 tie as values
+        x = jax.random.normal(key, (rows, C), jnp.float32)
+        return x.at[:, ::3].set(0.0).at[:, 1::4].set(-0.0)
     if name == "sorted":
         return jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32), (rows, C))
     if name == "reversed":
@@ -54,8 +57,13 @@ def _rows(name: str, C: int, rows: int = 3):
     raise AssertionError(name)
 
 
+def _bits(x):
+    """Keys as raw 32-bit patterns (floats compare by bits, not value)."""
+    return np.asarray(x).view(np.int32)
+
+
 GRID_NAMES = ("dups_int", "rand_int", "sentinel_int", "rand_float",
-              "sentinel_float", "sorted", "reversed")
+              "sentinel_float", "signed_zeros", "sorted", "reversed")
 # C=96 -> 3 leaves of 32 (non-power-of-two leaf count), C=1/5/257 ->
 # in-VMEM sentinel padding, C=256 -> the clean power-of-two lane
 GRID_C = (1, 5, 96, 256, 257)
@@ -68,8 +76,11 @@ GRID_C = (1, 5, 96, 256, 257)
 @pytest.mark.parametrize("C", GRID_C)
 def test_local_sort_matches_jnp_sort(name, C):
     x = _rows(name, C)
-    np.testing.assert_array_equal(np.asarray(ops.local_sort(x)),
-                                  np.sort(np.asarray(x), axis=-1))
+    out = np.asarray(ops.local_sort(x))
+    np.testing.assert_array_equal(out, np.sort(np.asarray(x), axis=-1))
+    # a permutation of the input bits: no -0.0/+0.0 or tie is duplicated
+    np.testing.assert_array_equal(np.sort(_bits(out), axis=-1),
+                                  np.sort(_bits(x), axis=-1))
 
 
 def test_local_sort_keeps_real_sentinels_with_padding():
@@ -94,11 +105,16 @@ def test_merge_split_matches_merge_sorted_slice(name, C):
     b = jnp.sort(_rows(name, C, rows)[::-1], axis=-1)
     keep = (jnp.arange(rows) % 2) == 0               # mixed per-row flags
     out = np.asarray(ops.merge_split(a, b, keep))
+    rest = np.asarray(ops.merge_split(a, b, ~keep))
     for r in range(rows):
         full = np.asarray(merge_sorted(a[r], b[r]))
         expect = full[:C] if bool(keep[r]) else full[C:]
         np.testing.assert_array_equal(out[r], expect,
                                       err_msg=f"{name} C={C} row={r}")
+        # the two halves together are a permutation of both runs' bits
+        np.testing.assert_array_equal(
+            np.sort(_bits(np.concatenate([out[r], rest[r]]))),
+            np.sort(_bits(np.concatenate([a[r], b[r]]))))
 
 
 def test_merge_split_scalar_flag_and_tie_stability():
@@ -112,19 +128,28 @@ def test_merge_split_scalar_flag_and_tie_stability():
         np.testing.assert_array_equal(got, full[:4] if keep else full[4:])
 
 
-@pytest.mark.skipif(ON_CPU, reason="interpret=False needs a real accelerator "
-                                   "(TPU); CPU only runs interpret mode")
-def test_kernels_compiled_mode_matches_interpret():
-    x = _rows("rand_int", 256)
-    np.testing.assert_array_equal(
-        np.asarray(ops.local_sort(x, interpret=False)),
-        np.asarray(ops.local_sort(x, interpret=True)))
-    a = jnp.sort(_rows("dups_int", 128), axis=-1)
-    b = jnp.sort(_rows("rand_int", 128), axis=-1)
+@pytest.mark.skipif(jax.default_backend() != "tpu",
+                    reason="compiled kernels need a TPU; the CPU runs the "
+                           "interpreter (test_chip_compile lowers them)")
+@pytest.mark.parametrize("name", ("rand_int", "dups_int", "signed_zeros"))
+@pytest.mark.parametrize("C", (128, 256, 1000))
+def test_kernels_compiled_mode_matches_interpret(name, C):
+    """Below one (8, 128) tile and off a power of two: the compiled padding
+    paths (HBM pad, in-VMEM sentinel prologue) equal the interpreter."""
+    from repro.kernels.local_sort import local_sort
+    from repro.kernels.merge_split import merge_split
+    x = _rows(name, C)
+    got = local_sort(x, interpret=False)
+    np.testing.assert_array_equal(_bits(got),
+                                  _bits(local_sort(x, interpret=True)))
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.sort(np.asarray(x), axis=-1))
+    a = jnp.sort(_rows(name, C), axis=-1)
+    b = jnp.sort(_rows(name, C)[::-1], axis=-1)
     keep = jnp.asarray([True, False, True])
     np.testing.assert_array_equal(
-        np.asarray(ops.merge_split(a, b, keep, interpret=False)),
-        np.asarray(ops.merge_split(a, b, keep, interpret=True)))
+        _bits(merge_split(a, b, keep, interpret=False)),
+        _bits(merge_split(a, b, keep, interpret=True)))
 
 
 # ---------------------------------------------------------------------------
@@ -139,17 +164,25 @@ ENGINE_POLICIES = [LocalisationPolicy(True, True, Homing.LOCAL_CHUNKED),
 @pytest.mark.parametrize("policy", ENGINE_POLICIES,
                          ids=lambda p: p.name)
 def test_engine_single_device_bit_exact_per_phase(policy, local_phase):
-    """1-device mesh, n=1000 => padded chunk, non-power-of-two leaves."""
-    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    """1-device mesh, n=1000 => padded chunk, non-power-of-two leaves;
+    float keys with signed zeros equal as values and as a bit permutation."""
+    mesh = jax.make_mesh((len(jax.devices()),), ("data",),
+                         axis_types=(AxisType.Auto,))
     fn = Locale(mesh=mesh, policy=policy).workload(
         "engine", num_workers=8, local_phase=local_phase)
-    for n, dt in ((1000, jnp.int32), (513, jnp.float32)):
-        x = (jax.random.randint(jax.random.key(n), (n,), -10**5, 10**5,
-                                dtype=dt) if dt == jnp.int32
-             else jax.random.normal(jax.random.key(n), (n,), dt))
-        expect = np.sort(np.asarray(x))
-        np.testing.assert_array_equal(np.asarray(fn(x)), expect,
+    signed_zeros = _rows("signed_zeros", 777, rows=1)[0]
+    for x in (jax.random.randint(jax.random.key(1000), (1000,), -10**5, 10**5,
+                                 dtype=jnp.int32),
+              jax.random.normal(jax.random.key(513), (513,), jnp.float32),
+              signed_zeros):
+        x = np.asarray(x)                 # the engine donates its input
+        expect = np.sort(x)
+        got = np.asarray(fn(jnp.asarray(x)))
+        np.testing.assert_array_equal(got, expect,
                                       err_msg=f"{policy.name} {local_phase}")
+        # -0.0 and +0.0 tie as values; their bits are kept, not rewritten
+        np.testing.assert_array_equal(np.sort(_bits(got)),
+                                      np.sort(_bits(x)))
 
 
 def test_local_phase_validation():

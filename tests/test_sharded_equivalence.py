@@ -14,7 +14,7 @@ CODE = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from functools import partial
 from repro.configs import get_config, reduce_config
 from repro.configs.base import ShapeSpec
@@ -29,7 +29,8 @@ for name in ["qwen3-0.6b", "mixtral-8x7b", "mamba2-2.7b", "jamba-1.5-large-398b"
     # heads=4/kv=2 on a 2-way model axis exercises TP + the GQA paths
     cfg = base.replace(parallel=base.parallel.__class__(
         fsdp=True, sequence_shard=True, remat=True, microbatches=2))
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     shape = ShapeSpec("t", 32, 4, "train")
     plan = make_plan(mesh, cfg, shape)
     model = LM(cfg)

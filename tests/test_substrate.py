@@ -56,11 +56,12 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np
 import jax
+from jax.sharding import AxisType
 from repro.configs import get_config, reduce_config
 from repro.data import SyntheticLM
 for arch in ("qwen3-0.6b", "musicgen-medium", "llama-3.2-vision-90b"):
     cfg = reduce_config(get_config(arch))
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
     a = SyntheticLM(cfg, 8, 16, seed=5, mesh=mesh, striped=True).batch(2)
     b = SyntheticLM(cfg, 8, 16, seed=5, mesh=mesh, striped=False).batch(2)
     assert set(a) == set(b)
@@ -145,11 +146,11 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from functools import partial
 from repro.optim.compression import compressed_psum
-from jax.experimental.shard_map import shard_map
-mesh = jax.make_mesh((4,), ("d",))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4,), ("d",), axis_types=(AxisType.Auto,))
 x = jax.random.normal(jax.random.key(0), (4, 256)) * 3
-f = jax.jit(shard_map(partial(compressed_psum, axis_name="d"),
-    mesh=mesh, in_specs=P("d"), out_specs=P(None), check_rep=False))
+f = jax.jit(jax.shard_map(partial(compressed_psum, axis_name="d"),
+    mesh=mesh, in_specs=P("d"), out_specs=P(None), check_vma=False))
 out = np.asarray(f(x))[0]
 expect = np.asarray(x).sum(0)
 err = np.abs(out - expect).max()
