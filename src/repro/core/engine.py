@@ -96,7 +96,8 @@ from jax.sharding import PartitionSpec as P
 from repro.core.homing import Axis, Homing, axis_tuple
 from repro.core.localisation import LocalisationPolicy, chunk_bounds
 from repro.core.sort import (check_pad_outside_trace, merge_sorted,
-                             pad_to_multiple)
+                             pad_to_multiple, sort_entry, sort_prepare,
+                             sort_unpad)
 from repro.kernels.local_sort import local_sort as _local_sort_kernel
 from repro.kernels.merge_split import merge_split as _merge_split_kernel
 from repro.obs.tracelog import get_tracer
@@ -684,8 +685,16 @@ def make_engine_fn(mesh: Optional[Mesh], policy: LocalisationPolicy,
                    num_workers: Optional[int] = None,
                    local_sort: LocalSort = "bitonic", axis: Axis = AXIS,
                    local_phase: Optional[str] = None):
-    """Jitted engine sort for one Table-1 case; input donated (step 5)."""
-    from repro.core.sort import sort_entry          # local: avoid cycle
+    """Jitted engine sort for one Table-1 case; input donated (step 5).
+
+    With the global tracer on, each call records an ``engine.sort`` span
+    holding the host work of the call as three children, all stamped with
+    the call's id: ``sort.prepare`` (array coercion, NaN guard, padding),
+    ``sort.dispatch`` (the jitted call until it returns: the enqueue, and
+    on a call that builds the program also its trace, lowering and compile
+    or cache load; ``build`` says which) and ``sort.unpad`` (the strip).
+    The ``sort.builds`` counter moves each time JAX traces the program.
+    """
     resolve_local_phase(local_phase, local_sort)    # fail fast, not at trace
     if mesh is None:
         a = axis if isinstance(axis, str) else axis[-1]
@@ -693,41 +702,57 @@ def make_engine_fn(mesh: Optional[Mesh], policy: LocalisationPolicy,
                              axis_types=(AxisType.Auto,))
         axis = a
     axes = axis_tuple(axis)
-    m = math.prod(_axes_sizes(mesh, axes))
+    sizes = _axes_sizes(mesh, axes)
+    m = math.prod(sizes)
     hash_homed = policy.homing == Homing.HASH_INTERLEAVED
     granule = engine_granule(m, num_workers, hash_homed)
+
     fn = partial(shard_map_sort, mesh=mesh, policy=policy,
                  num_workers=num_workers, local_sort=local_sort,
                  axis=axis, local_phase=local_phase)
-    entry = sort_entry(jax.jit(fn, donate_argnums=(0,)), granule)
-    sizes = _axes_sizes(mesh, axes)
+
+    @functools.wraps(shard_map_sort)
+    def build(x, *a, **kw):
+        # the body runs only while JAX traces: one count per program build
+        get_tracer().count("sort.builds", cat="engine", n=int(x.shape[0]),
+                           sizes=list(sizes))
+        return fn(x, *a, **kw)
+
+    jitted = jax.jit(build, donate_argnums=(0,))
+    entry = sort_entry(jitted, granule)
 
     @functools.wraps(entry)
     def traced(x, *a, **kw):
         tr = get_tracer()
         if not tr.enabled:
             return entry(x, *a, **kw)
-        x = jnp.asarray(x)
-        n = int(x.shape[0])
-        itemsize = jnp.dtype(x.dtype).itemsize
         cid = next(_SORT_CALLS)
         # the span stamps everything the reconciler needs to recompute
         # exchange_schedule(n, sizes, policy) and check the stamped
         # per-level budgets against it — the trace carries the analytic
         # byte budget right next to the scheduler's observed charges
-        with tr.span("engine.sort", cat="engine", call=cid, n=n,
+        with tr.span("engine.sort", cat="engine", call=cid,
                      sizes=list(sizes), num_workers=num_workers,
-                     itemsize=itemsize, local_phase=local_phase,
+                     local_phase=local_phase,
                      policy={"localised": policy.localised,
                              "static_mapping": policy.static_mapping,
                              "homing": policy.homing.name,
                              "outer": policy.outer}) as sp:
+            with tr.span("sort.prepare", cat="engine", call=cid):
+                x, n = sort_prepare(x, granule)
+            itemsize = jnp.dtype(x.dtype).itemsize
+            sp.set(n=n, itemsize=itemsize)
             for lr in exchange_schedule(n, sizes, policy,
                                         num_workers=num_workers,
                                         itemsize=itemsize,
                                         local_phase=local_phase):
                 sp.event("engine.exchange_level", call=cid, **lr)
-            return entry(x, *a, **kw)
+            with tr.span("sort.dispatch", cat="engine", call=cid) as dp:
+                builds = tr.total("sort.builds")
+                y = jitted(x, *a, **kw)
+                dp.set(build=tr.total("sort.builds") > builds)
+            with tr.span("sort.unpad", cat="engine", call=cid):
+                return sort_unpad(y, n)
 
     traced.lower = entry.lower
     traced.__wrapped__ = entry.__wrapped__

@@ -70,14 +70,26 @@ def sort_entry(jitted, granule: int):
     """
     @functools.wraps(getattr(jitted, "__wrapped__", jitted))
     def call(x, *args, **kw):
-        x = jnp.asarray(x)              # jit coerced sequences; keep doing so
-        check_nan_free(x, "sort")       # pad skips its own scan: one pass
-        n = x.shape[0]
-        return jitted(pad_to_multiple(x, granule, nan_check=False),
-                      *args, **kw)[:n]
+        x, n = sort_prepare(x, granule)
+        return sort_unpad(jitted(x, *args, **kw), n)
     call.lower = jitted.lower
     call.__wrapped__ = jitted
     return call
+
+
+def sort_prepare(x, granule: int):
+    """`sort_entry`'s eager first half: ``(padded x, caller's length)``.
+
+    Coerces ``x`` to an array, raises on NaN, and pads with BIG sentinels
+    up to a multiple of ``granule``."""
+    x = jnp.asarray(x)                  # jit coerced sequences; keep doing so
+    check_nan_free(x, "sort")           # pad skips its own scan: one pass
+    return pad_to_multiple(x, granule, nan_check=False), x.shape[0]
+
+
+def sort_unpad(y, n: int):
+    """`sort_entry`'s eager second half: strip the sentinel tail."""
+    return y[:n]
 
 
 def pad_value(dtype):

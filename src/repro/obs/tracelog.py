@@ -31,7 +31,10 @@ eager sort entry); everything else takes an explicit ``tracer=``.
 Timestamps are wall-clock microseconds since the tracer's epoch (what
 Chrome wants); deterministic simulated clocks (the scheduler's wave
 units) ride in ``args`` (``now=...``) so reconciliation never depends on
-wall time.
+wall time.  The epoch is public (`Tracer.epoch`, a ``time.perf_counter()``
+reading), so ``epoch + ts / 1e6`` puts a record on the ``perf_counter``
+clock, and from there on any other clock calibrated against it, such as a
+``jax.profiler`` trace's.
 """
 from __future__ import annotations
 
@@ -205,7 +208,9 @@ class Tracer:
         self._records: List[Dict[str, Any]] = []
         self._totals: Dict[str, float] = {}
         self._local = threading.local()
-        self._epoch = time.perf_counter()
+        #: the ``time.perf_counter()`` reading that record ``ts`` values
+        #: count from: ``epoch + ts / 1e6`` is a ``perf_counter`` time
+        self.epoch = time.perf_counter()
         self._file = None
         self._own_file = False
         if isinstance(sink, str):
@@ -217,7 +222,7 @@ class Tracer:
 
     # ------------------------------------------------------------ internals
     def _now(self) -> float:
-        return (time.perf_counter() - self._epoch) * 1e6   # us since epoch
+        return (time.perf_counter() - self.epoch) * 1e6   # us since epoch
 
     def _stack(self) -> List[Span]:
         st = getattr(self._local, "stack", None)
@@ -262,18 +267,6 @@ class Tracer:
     def total(self, name: str) -> float:
         with self._lock:
             return self._totals.get(name, 0)
-
-    def chrome_trace(self) -> Dict[str, Any]:
-        return to_chrome(self.records())
-
-    def dump_jsonl(self, path: str) -> None:
-        with open(path, "w") as f:
-            for rec in self.records():
-                f.write(json.dumps(rec) + "\n")
-
-    def dump_chrome(self, path: str) -> None:
-        with open(path, "w") as f:
-            json.dump(self.chrome_trace(), f)
 
     def close(self) -> None:
         with self._lock:

@@ -253,6 +253,91 @@ def reconcile_engine_only(records):
     check_engine(records)
 
 
+def engine_sorts(tracer, *xs):
+    """Sort each of ``xs`` through one engine fn with ``tracer`` installed
+    as the global tracer (None: tracing off)."""
+    from repro.core.engine import make_engine_fn
+    from repro.core.localisation import LocalisationPolicy
+    fn = make_engine_fn(None, LocalisationPolicy())
+    prev = set_tracer(tracer)
+    try:
+        return [np.asarray(fn(x)) for x in xs]
+    finally:
+        set_tracer(prev)
+
+
+@pytest.fixture(scope="module")
+def traced_engine_sorts():
+    """Two calls of the same shape under one tracer: the first builds."""
+    x = np.random.RandomState(1).randint(-50, 50, 100).astype(np.int32)
+    tr = Tracer()
+    ys = engine_sorts(tr, x, x)
+    return tr, x, ys
+
+
+@pytest.mark.parametrize("n", [100, 128])
+def test_engine_sort_off_path_records_nothing(traced_engine_sorts, n):
+    """Tracing off: the same result as the traced path, and a tracer that
+    was installed before records nothing more, not even a build."""
+    tr, _, _ = traced_engine_sorts
+    held = len(tr.records())
+    x = np.random.RandomState(n).randint(-50, 50, n).astype(np.int32)
+    (off,) = engine_sorts(None, x)
+    (on,) = engine_sorts(Tracer(), x)
+    assert off.dtype == x.dtype and (off == np.sort(x)).all()
+    assert (off == on).all()
+    assert get_tracer() is NULL_TRACER
+    assert len(tr.records()) == held
+
+
+def test_engine_sort_spans_cover_the_host_path(traced_engine_sorts):
+    tr, x, ys = traced_engine_sorts
+    assert all((y == np.sort(x)).all() for y in ys)
+    recs = tr.records()
+    sorts = [r for r in recs if r["name"] == "engine.sort"]
+    assert len(sorts) == 2
+    for top in sorts:
+        cid = top["args"]["call"]
+        kids = sorted((r for r in recs if r["kind"] == "span"
+                       and r["parent"] == "engine.sort"
+                       and r["args"]["call"] == cid), key=lambda r: r["ts"])
+        assert [r["name"] for r in kids] == ["sort.prepare", "sort.dispatch",
+                                             "sort.unpad"]
+        t, end = top["ts"], top["ts"] + top["dur"]
+        for r in kids:
+            assert t <= r["ts"] and r["ts"] + r["dur"] <= end
+            t = r["ts"] + r["dur"]
+    reconcile_engine_only(recs)
+
+
+def test_engine_sort_marks_the_call_that_builds(traced_engine_sorts):
+    import jax
+    tr, x, _ = traced_engine_sorts
+    recs = tr.records()
+    builds = [r["args"]["build"] for r in recs
+              if r["name"] == "sort.dispatch"]
+    assert builds == [True, False]
+    counts = [r for r in recs if r["name"] == "sort.builds"]
+    assert len(counts) == 1 and tr.total("sort.builds") == 1
+    assert counts[0]["args"]["n"] >= len(x)
+    assert counts[0]["args"]["sizes"] == [len(jax.devices())]
+    # the build happened inside the first call's dispatch
+    first = next(r for r in recs if r["name"] == "sort.dispatch")
+    assert first["ts"] <= counts[0]["ts"] <= first["ts"] + first["dur"]
+
+
+def test_tracer_epoch_is_on_the_perf_counter_clock():
+    import time
+    tr = Tracer()
+    t0 = time.perf_counter()
+    with tr.span("s"):
+        pass
+    t1 = time.perf_counter()
+    (s,) = [r for r in tr.records() if r["name"] == "s"]
+    start = tr.epoch + s["ts"] / 1e6
+    assert t0 <= start <= start + s["dur"] / 1e6 <= t1
+
+
 # ---------------------------------------------------------------------------
 # supervisor fleet events
 # ---------------------------------------------------------------------------
