@@ -40,8 +40,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import (VMEM_BYTES_PER_CORE, VMEM_HEADROOM,
                            resolve_interpret, vmem_bytes)
-from repro.kernels.bitonic_sort import (KEY_MAX, LANES, from_keys, padded_len,
-                                        sort_network, to_keys)
+from repro.kernels.bitonic_sort import (KEY_MAX, LANES, block_keys, from_keys,
+                                        padded_len, sort_network, sweep_plan,
+                                        to_keys)
+from repro.obs.tracelog import get_tracer
 
 
 def max_chunk() -> int:
@@ -62,6 +64,20 @@ def _kernel(x_hbm, o_hbm, w, *, C: int):
     pltpu.sync_copy(data, o_hbm.at[i])
 
 
+def _count_sweeps(rows: int, L: int) -> None:
+    """Count the network's VMEM sweeps and the substages it applies inside
+    a register block (no VMEM round trip between them), for all ``rows``
+    grid steps.  Runs when the call is traced."""
+    tr = get_tracer()
+    if not tr.enabled:
+        return
+    plan, B = sweep_plan(L), block_keys(L)
+    tr.count("local_sort.sweeps", rows * len(plan), cat="kernel", L=L)
+    tr.count("local_sort.register_substages",
+             rows * sum(len(s) for s in plan if s[0][1] < B),
+             cat="kernel", L=L)
+
+
 def local_sort(x, *, interpret: Optional[bool] = None):
     """Sort each row of x: (rows, C) -> (rows, C), any C >= 1.
 
@@ -72,6 +88,7 @@ def local_sort(x, *, interpret: Optional[bool] = None):
     """
     rows, C = x.shape
     L = padded_len(C)
+    _count_sweeps(rows, L)
     keys = to_keys(x)
     Cr = -(-C // LANES) * LANES
     if Cr != C:

@@ -23,6 +23,10 @@ from repro.core import (LOCAL_PHASES, Homing, Locale, LocalisationPolicy,
                         exchange_schedule)
 from repro.core.sort import merge_sorted
 from repro.kernels import ops
+from repro.kernels.bitonic_sort import (BLOCK_ROWS, LANES, TILE, block_keys,
+                                        sweep_plan)
+from repro.kernels.local_sort import local_sort
+from repro.obs import Tracer, set_tracer
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:                 # for the in-process benchmark tests
@@ -92,6 +96,105 @@ def test_local_sort_keeps_real_sentinels_with_padding():
     np.testing.assert_array_equal(np.asarray(ops.local_sort(xf))[0],
                                   np.asarray([-np.inf, 0.5, np.inf],
                                              np.float32))
+
+
+# ---------------------------------------------------------------------------
+# the register-blocked network: its sweep plan, and sizes past one block
+# ---------------------------------------------------------------------------
+def _network(L: int):
+    """Every (k, j) substage of the bitonic network on L keys, in order."""
+    out, k = [], 2
+    while k <= L:
+        j = k // 2
+        while j >= 1:
+            out.append((k, j))
+            j //= 2
+        k *= 2
+    return out
+
+
+def _in_registers(plan, B):
+    return sum(len(s) for s in plan if s[0][1] < B)
+
+
+@pytest.mark.parametrize("block_rows", (8, 32, 64, 128, 256))
+def test_sweep_plan_applies_every_substage_once_in_order(block_rows):
+    for e in range(TILE.bit_length() - 1, 25):          # one tile .. 2^24
+        L = 1 << e
+        plan = sweep_plan(L, block_rows)
+        assert [sub for sweep in plan for sub in sweep] == _network(L), L
+        B = block_keys(L, block_rows)
+        for sweep in plan:        # a row sweep: one stride past the block
+            strides = [j for _, j in sweep]
+            assert (len(sweep) == 1 and strides[0] >= B
+                    or max(strides) < B), (L, sweep)
+
+
+@pytest.mark.parametrize("block_rows, sweeps, iterations, row_iterations, "
+                         "in_registers",
+                         [(8, 105, None, None, 185),    # one tile a block
+                          (64, 66, 39424, 28160, 221),
+                          (128, 55, 16640, 11520, 231),
+                          (256, 45, 6912, 4608, 240),
+                          (512, 36, 2816, 1792, 248)])
+def test_sweep_plan_counts_at_2_23_keys(block_rows, sweeps, iterations,
+                                        row_iterations, in_registers):
+    L = 1 << 23
+    plan = sweep_plan(L, block_rows)
+    B = block_keys(L, block_rows)
+    rows = [s for s in plan if s[0][1] >= B]
+    assert len(plan) == sweeps
+    assert _in_registers(plan, B) == in_registers
+    assert len(_network(L)) == 276
+    if iterations is not None:    # a block a block-sweep step, two a row's
+        row_iters = len(rows) * (L // (2 * B))
+        assert row_iters == row_iterations
+        assert (len(plan) - len(rows)) * (L // B) + row_iters == iterations
+
+
+BLOCK = BLOCK_ROWS * LANES               # keys in one register block
+
+
+@pytest.mark.parametrize("name, C, rows", [
+    ("rand_int", 1 << 15, 2),            # cross-register strides
+    ("rand_int", 2 * BLOCK, 2),          # and a row sweep
+    ("rand_int", 4 * BLOCK, 1),
+    ("dups_int", 2 * BLOCK, 2),
+    ("signed_zeros", 2 * BLOCK + 37, 1)])    # float, sentinel tail
+def test_local_sort_past_one_register_block(name, C, rows):
+    x = _rows(name, C, rows)
+    out = local_sort(x)
+    # bit-exact: floats in total order, -0.0 before +0.0
+    flip = (lambda k: k ^ ((k >> 31) & BIGI)) if x.dtype == jnp.float32 \
+        else (lambda k: k)
+    np.testing.assert_array_equal(_bits(out),
+                                  flip(np.sort(flip(_bits(x)), axis=-1)))
+
+
+def test_merge_split_past_one_register_block():
+    C, rows = 2 * BLOCK, 2
+    a = jnp.sort(_rows("rand_int", C, rows), axis=-1)
+    b = jnp.sort(_rows("dups_int", C, rows), axis=-1)
+    keep = jnp.asarray([True, False])
+    out = np.asarray(ops.merge_split(a, b, keep))
+    for r in range(rows):
+        full = np.asarray(merge_sorted(a[r], b[r]))
+        np.testing.assert_array_equal(out[r], full[:C] if r == 0 else full[C:])
+
+
+@pytest.mark.parametrize("L", (TILE, 1 << 15, 1 << 23))
+def test_local_sort_counts_the_sweep_plan(L):
+    rows = 2
+    tr = Tracer()
+    prev = set_tracer(tr)
+    try:                                  # trace only: the counts are static
+        jax.make_jaxpr(local_sort)(jax.ShapeDtypeStruct((rows, L), jnp.int32))
+    finally:
+        set_tracer(prev)
+    plan = sweep_plan(L)
+    assert tr.total("local_sort.sweeps") == rows * len(plan)
+    assert (tr.total("local_sort.register_substages")
+            == rows * _in_registers(plan, block_keys(L)))
 
 
 # ---------------------------------------------------------------------------
