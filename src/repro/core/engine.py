@@ -72,6 +72,13 @@ implementations, selected by ``local_phase``:
                  of HBM-materialising vmapped rank merges, and
                  merge-everything-discard-half at every network substage.
 
+A "pallas" chunk larger than one `local_sort` holds in VMEM (`run_len`)
+streams past VMEM instead: ONE `local_sort` call sorts it as VMEM-sized
+runs, one grid row each, and the block bitonic network of the exchange —
+with the runs standing in for the devices — merges them in HBM, one
+batched `kernels.merge_split.run_merge` call per substage (`_sort_runs`).
+The path is chosen by the chunk's size alone, on one device only.
+
 ``local_phase=None`` auto-selects: "pallas" for the default
 ``local_sort="bitonic"``, "reference" when a callable leaf sort is given
 (a callable can't be fused into the kernel).  The non-localised path's
@@ -98,8 +105,12 @@ from repro.core.localisation import LocalisationPolicy, chunk_bounds
 from repro.core.sort import (check_pad_outside_trace, merge_sorted,
                              pad_to_multiple, sort_entry, sort_prepare,
                              sort_unpad)
+from repro.kernels.bitonic_sort import KEY_MAX, from_keys, to_keys
 from repro.kernels.local_sort import local_sort as _local_sort_kernel
+from repro.kernels.local_sort import max_chunk
+from repro.kernels.merge_split import max_run
 from repro.kernels.merge_split import merge_split as _merge_split_kernel
+from repro.kernels.merge_split import run_merge as _run_merge_kernel
 from repro.obs.tracelog import get_tracer
 
 #: engine.sort span ids — groups a span's engine.exchange_level events
@@ -370,6 +381,44 @@ def exchange_network(policy: LocalisationPolicy, sizes: Sequence[int],
         levels=tuple(levels))
 
 
+def run_len(chunk: int) -> int:
+    """Keys in each run of the "pallas" local phase for a chunk of 32-bit
+    keys: the whole chunk where one `local_sort` holds it in VMEM, else the
+    longest run of which `run_merge` holds two."""
+    return chunk if chunk <= max_chunk() else max_run()
+
+
+def run_network(runs: int) -> ExchangeNetwork:
+    """The block bitonic network that merges ``runs`` sorted runs of one
+    chunk: the exchange network over a power of two of runs, each run in a
+    device's place (sentinel runs fill the power of two)."""
+    p = 1 << max(0, (runs - 1).bit_length())
+    return exchange_network(LocalisationPolicy(), (p,))
+
+
+def _sort_runs(x):
+    """Sort a chunk past one `local_sort`'s VMEM: runs of `run_len` keys (the
+    last one padded with sentinels), formed by ONE `local_sort` call, then
+    merged in HBM by `run_network`, one `run_merge` call per substage."""
+    chunk = x.shape[0]
+    run = run_len(chunk)
+    runs = -(-chunk // run)
+    net = run_network(runs)
+    tr = get_tracer()
+    tr.count("sort.runs", runs, cat="engine", run=run)
+    tr.count("sort.run_merge_substages", len(net.levels), cat="engine",
+             runs=net.m)
+    keys = jnp.pad(to_keys(x), (0, runs * run - chunk),
+                   constant_values=KEY_MAX)
+    keys = _local_sort_kernel(keys.reshape(runs, run))
+    keys = jnp.pad(keys, ((0, net.m - runs), (0, 0)),
+                   constant_values=KEY_MAX)
+    for ex in net.levels:
+        keys = _run_merge_kernel(keys, np.asarray(ex.partner),
+                                 np.asarray(ex.keep_low))
+    return from_keys(keys.reshape(-1)[:chunk], x.dtype)
+
+
 def _localised_shard(xloc, *, m: int, chunk: int, w_per_dev: int,
                      hash_homed: bool, local_sort: LocalSort,
                      axes: Tuple[str, ...], sizes: Tuple[int, ...],
@@ -383,7 +432,9 @@ def _localised_shard(xloc, *, m: int, chunk: int, w_per_dev: int,
         mine = jax.lax.all_to_all(blocks, name, 0, 0).reshape(-1)
     else:
         mine = xloc                       # already the locally-homed chunk
-    if local_phase == "pallas":
+    if local_phase == "pallas" and run_len(chunk) < chunk:
+        run = _sort_runs(mine)            # one device only (`_check_runs`)
+    elif local_phase == "pallas":
         # Algorithm 2 for the whole local phase: ONE pallas_call copies my
         # chunk into VMEM, runs the leaf stages AND the full local merge
         # tree on-chip, and writes the sorted run back once.
@@ -481,6 +532,19 @@ def _unlocalised_shard(xloc, *, m: int, chunk: int, w: int,
     return xloc
 
 
+def _check_runs(chunk: int, m: int, policy: LocalisationPolicy,
+                local_phase: str) -> None:
+    """The run path past VMEM sorts one device's chunk; across devices the
+    merge-split network would hold two such chunks in VMEM."""
+    if (m > 1 and policy.localised and local_phase == "pallas"
+            and run_len(chunk) < chunk):
+        raise ValueError(
+            f"a {chunk}-key chunk per device is past one local sort's VMEM "
+            f"({max_chunk()} keys): the pallas local phase streams past VMEM "
+            f"on one device only, and this mesh has {m}; use more devices "
+            f"or local_phase='reference'")
+
+
 def shard_map_sort(x, mesh: Mesh,
                    policy: LocalisationPolicy = LocalisationPolicy(),
                    num_workers: Optional[int] = None,
@@ -513,6 +577,8 @@ def shard_map_sort(x, mesh: Mesh,
     bounds = chunk_bounds(n_p, m)                  # ownership, paper step 1
     chunk = bounds[0][1] - bounds[0][0]
     assert all(hi - lo == chunk for lo, hi in bounds)
+
+    _check_runs(chunk, m, policy, local_phase)
 
     spec_axis = axes[0] if len(axes) == 1 else axes   # P entry: name | tuple
     if hash_homed:
@@ -577,6 +643,14 @@ def exchange_schedule(n: int, sizes: Sequence[int],
                    reference: 7B traffic (read 2B, write the 2C merge,
                               re-read it, write the kept half), 2C elems
 
+    A "pallas" chunk past one local sort's VMEM (`run_len`) is priced as
+    what `_sort_runs` runs, all at level 0: one ``local_sort`` record for
+    run formation (2 bytes of traffic per key of the R runs, the last one
+    sentinel-padded), then one ``run_merge`` record per substage of
+    `run_network` (every one of its P runs, sentinel runs included, reads
+    its own run and its partner's and writes the kept half: 3 bytes of
+    traffic per key).
+
     Must mirror the shard_map bodies above; the structure tests pin the
     collective records to the lowered HLO's collective counts.
     """
@@ -630,9 +704,20 @@ def exchange_schedule(n: int, sizes: Sequence[int],
         rec(0, "all_to_all",
             m * (m - m_inner) * (B // m), m * (m_inner - 1) * (B // m))
     tree = max(0, (w // m).bit_length() - 1)        # local merge-tree levels
-    rec(0, "local_sort", 0, 0,
-        hbm=2 * n_p * itemsize * (1 if pallas else 1 + tree),
-        elems=n_p * (1 if pallas else 1 + tree))
+    _check_runs(chunk, m, policy, local_phase)
+    run = run_len(chunk) if pallas else chunk
+    if run < chunk:
+        runs = -(-chunk // run)
+        rec(0, "local_sort", 0, 0, hbm=2 * m * runs * run * itemsize,
+            elems=m * runs * run)
+        net = run_network(runs)
+        for _ in net.levels:
+            rec(0, "run_merge", 0, 0, hbm=3 * m * net.m * run * itemsize,
+                elems=m * net.m * run)
+    else:
+        rec(0, "local_sort", 0, 0,
+            hbm=2 * n_p * itemsize * (1 if pallas else 1 + tree),
+            elems=n_p * (1 if pallas else 1 + tree))
     for i in range(m.bit_length() - 1):
         j0 = i
         if hier and i >= log_inner:

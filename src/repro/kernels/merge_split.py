@@ -23,6 +23,13 @@ sentinels included; keys that compare equal but differ in bits (``-0.0`` and
 The keep flag is a per-row scalar read from SMEM.  The batched form is the
 hierarchical engine's cross-pod replay unit: row r merges pod r's chunk with
 its partner pod's chunk under its own keep flag.
+
+`run_merge` is the same merge-split over the rows of one array, each row's
+partner row read from an SMEM table: one substage of the block bitonic
+network that merges a chunk's VMEM-sized runs in HBM (the engine's local
+phase past one `local_sort`).  Its pallas_call has its own name, so a
+device trace tells it from the cross-chip merge-split.  `max_run` is the
+longest run whose pair fits `VMEM_BYTES_PER_CORE`.
 """
 from __future__ import annotations
 
@@ -33,7 +40,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import resolve_interpret, vmem_bytes
+from repro.kernels import (VMEM_BYTES_PER_CORE, VMEM_HEADROOM,
+                           resolve_interpret, vmem_bytes)
 from repro.kernels.bitonic_sort import (KEY_MAX, KEY_MIN, LANES, SUBLANES,
                                         TILE, from_keys, merge_stage,
                                         padded_len, to_keys)
@@ -52,11 +60,31 @@ def _reverse_tile(v):
     return v
 
 
+def max_run() -> int:
+    """Longest power-of-two run of 32-bit keys whose merge-split holds both
+    runs in VMEM."""
+    return 1 << ((VMEM_BYTES_PER_CORE - VMEM_HEADROOM) // 8).bit_length() - 1
+
+
 def _kernel(keep_ref, a_hbm, b_hbm, o_hbm, w, bw):
     i = pl.program_id(0)
     pltpu.sync_copy(a_hbm.at[i], w)
     pltpu.sync_copy(b_hbm.at[i], bw)
-    keep_low = keep_ref[i] != 0
+    _split(w, bw, keep_ref[i] != 0)
+    pltpu.sync_copy(w, o_hbm.at[i])
+
+
+def _run_kernel(partner_ref, keep_ref, x_hbm, o_hbm, w, bw):
+    i = pl.program_id(0)
+    pltpu.sync_copy(x_hbm.at[i], w)
+    pltpu.sync_copy(x_hbm.at[partner_ref[i]], bw)
+    _split(w, bw, keep_ref[i] != 0)
+    pltpu.sync_copy(w, o_hbm.at[i])
+
+
+def _split(w, bw, keep_low):
+    """Leave in ``w`` the kept half of the merge of sorted runs ``w`` and
+    ``bw``, sorted."""
     tiles = w.shape[0] // SUBLANES
 
     def half_clean(t, carry):
@@ -70,7 +98,6 @@ def _kernel(keep_ref, a_hbm, b_hbm, o_hbm, w, bw):
 
     jax.lax.fori_loop(0, tiles, half_clean, 0)
     merge_stage(w, w.shape[0] * LANES)
-    pltpu.sync_copy(w, o_hbm.at[i])
 
 
 def _merge_split_keys(a, b, keep, interpret: bool):
@@ -128,3 +155,35 @@ def merge_split(a, b, keep_low, *, interpret: Optional[bool] = None):
     if pad:
         out = jnp.where(keep[:, None], out[:, :C], out[:, pad:])
     return from_keys(out, a.dtype)
+
+
+def run_merge(runs, partner, keep_low, *, interpret: Optional[bool] = None):
+    """One substage of a merge-split network over the rows of ``runs``.
+
+    runs: (rows, L) int32 keys, every row sorted, L a power of two of at
+    least one tile; partner, keep_low: static per-row tables (numpy).  Row
+    r of the result is the low (keep_low[r]) or high half of the merge of
+    rows r and partner[r], as `merge_split` gives it; nothing is gathered
+    in HBM, the kernel reads the partner row by DMA.
+    """
+    rows, L = runs.shape
+    if runs.dtype != jnp.int32 or L != padded_len(L):
+        raise ValueError(f"run_merge takes int32 keys in rows of a power "
+                         f"of two of at least {TILE}; got {runs.dtype} "
+                         f"rows of {L}")
+    shape = (rows, L // LANES, LANES)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        _run_kernel,
+        grid=(rows,),
+        in_specs=[smem, smem, hbm],
+        out_specs=hbm,
+        out_shape=jax.ShapeDtypeStruct(shape, jnp.int32),
+        scratch_shapes=[pltpu.VMEM(shape[1:], jnp.int32)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_bytes(2 * L * 4)),
+        interpret=resolve_interpret(interpret),
+        name="run_merge",
+    )(jnp.asarray(partner, jnp.int32), jnp.asarray(keep_low, jnp.int32),
+      runs.reshape(shape)).reshape(rows, L)

@@ -11,9 +11,16 @@ import sys
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
 
+import repro.kernels.local_sort
+import repro.kernels.merge_split
+from repro.analysis.homecheck import check_artifacts
+from repro.analysis.vmem import pallas_footprints
+from repro.core import Locale
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.local_sort import local_sort, max_chunk
 from repro.kernels.merge_split import merge_split
@@ -69,3 +76,27 @@ def test_flash_attention_compiles(one_chip):
                                                        interpret=False),
                        q, q, q)
     assert "tpu_custom_call" in hlo
+
+
+def test_engine_sort_past_vmem_compiles_at_100m_keys(one_chip, monkeypatch):
+    """The paper's Fig. 2 sort, 100M int32 keys on one chip, through the
+    engine: run formation and the run merge compile, every pallas_call fits
+    VMEM (rule R3) and the program fits HBM (rule R10)."""
+    for mod in (repro.kernels.local_sort, repro.kernels.merge_split):
+        monkeypatch.setattr(mod, "resolve_interpret", lambda i=None: False)
+    mesh = Mesh(np.array(list(one_chip.device_set)), ("data",),
+                axis_types=(AxisType.Auto,))
+    fn = Locale(mesh=mesh).workload("sort", backend="shard_map",
+                                    local_phase="pallas")
+    x = jax.ShapeDtypeStruct((100_000_000,), jnp.int32,
+                             sharding=NamedSharding(mesh, PartitionSpec("data")))
+    compiled = fn.lower(x).compile()
+    jaxpr = jax.make_jaxpr(fn.__wrapped__)(x)
+    # 12 runs of 2^23 keys in a network of 16: 10 substages
+    assert [f.name for f in pallas_footprints(jaxpr)] == \
+        ["local_sort"] + ["run_merge"] * 10
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= 2
+    rep = check_artifacts("sort-100m", hlo, jaxpr=jaxpr, rules=["R3", "R10"],
+                          memory_stats=compiled.memory_analysis())
+    assert rep.clean, rep.format()
