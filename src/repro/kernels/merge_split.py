@@ -10,11 +10,18 @@ This kernel never forms the 2C run.  For sorted ``a`` and ``b``, the C
 elementwise minima of ``a[i]`` and ``b[C-1-i]`` are exactly the low half of
 the merge and the C maxima the high half, each a bitonic sequence (Batcher's
 half-cleaner).  So per row it DMAs the two runs into VMEM, makes one pass
-that keeps the min or the max against ``b`` read back to front (tiles in
-reverse order, each reversed in registers by XOR-partner rotations — Mosaic
-has no ``rev``), then sorts the kept bitonic half with the last stage of the
-bitonic network: log2(C) half-cleaner substages.  O(C log C) on-chip work,
-2C reads and C writes of HBM, no 2C intermediate.
+that keeps the min or the max against ``b`` read back to front, then sorts
+the kept bitonic half with the last stage of the bitonic network: log2(C)
+half-cleaner substages.  O(C log C) on-chip work, 2C reads and C writes of
+HBM, no 2C intermediate.
+
+The back-to-front pass is register-blocked (`half_clean_blocks`), as the
+network's block sweeps are: each loop iteration loads ``HALF_CLEAN_ROWS``
+rows of ``w`` and the mirrored block of ``b`` as ``(n, 8, 128)`` values,
+reverses ``b``'s block whole (the register order by renaming, every tile by
+the same XOR-partner rotations at once — Mosaic has no ``rev``), and stores
+the min or the max.  So the chain of 10 rotation levels runs over n
+independent registers, not one.
 
 The result equals ``merge_sorted(a, b)[:C]`` / ``[C:]`` as values, ties and
 sentinels included; keys that compare equal but differ in bits (``-0.0`` and
@@ -43,17 +50,36 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import (VMEM_BYTES_PER_CORE, VMEM_HEADROOM,
                            resolve_interpret, vmem_bytes)
 from repro.kernels.bitonic_sort import (KEY_MAX, KEY_MIN, LANES, SUBLANES,
-                                        TILE, from_keys, merge_stage,
-                                        padded_len, to_keys)
+                                        TILE, _pairs, _unpairs, block_keys,
+                                        from_keys, merge_stage, padded_len,
+                                        to_keys)
+
+HALF_CLEAN_ROWS = 256            # rows the half-clean pass holds in registers
 
 
-def _reverse_tile(v):
-    """Reverse the TILE keys of one (8, 128) register: i -> i ^ (TILE - 1)."""
-    idx = (jax.lax.broadcasted_iota(jnp.int32, v.shape, 0) * LANES
-           + jax.lax.broadcasted_iota(jnp.int32, v.shape, 1))
+def half_clean_blocks(L: int) -> int:
+    """Loop iterations of the half-clean pass over a row of L keys: one
+    register block of ``HALF_CLEAN_ROWS`` rows (or the whole row) each."""
+    return L // block_keys(L, HALF_CLEAN_ROWS)
+
+
+def _reverse(v):
+    """Reverse the keys of block ``v`` (n, 8, 128): i -> i ^ (n * TILE - 1).
+
+    Registers t and t ^ d swap places by renaming (leading-dim reshapes);
+    inside every tile, key i takes key i ^ j by two rotations and a select,
+    for each bit j of the tile index.
+    """
+    d = v.shape[0] // 2
+    while d:
+        a, b = _pairs(v, d)
+        v = _unpairs(b, a)
+        d //= 2
+    idx = (jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 0) * LANES
+           + jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 1))
     j = 1
     while j < TILE:
-        axis, s, n = (1, j, LANES) if j < LANES else (0, j // LANES, SUBLANES)
+        axis, s, n = (2, j, LANES) if j < LANES else (1, j // LANES, SUBLANES)
         v = jnp.where((idx & j) == 0, pltpu.roll(v, n - s, axis),
                       pltpu.roll(v, s, axis))
         j *= 2
@@ -85,19 +111,29 @@ def _run_kernel(partner_ref, keep_ref, x_hbm, o_hbm, w, bw):
 def _split(w, bw, keep_low):
     """Leave in ``w`` the kept half of the merge of sorted runs ``w`` and
     ``bw``, sorted."""
-    tiles = w.shape[0] // SUBLANES
+    _half_clean(w, bw, keep_low)
+    merge_stage(w, w.shape[0] * LANES)
 
-    def half_clean(t, carry):
-        r = pl.multiple_of(t * SUBLANES, SUBLANES)
-        rb = pl.multiple_of((tiles - 1 - t) * SUBLANES, SUBLANES)
-        a = w[pl.ds(r, SUBLANES), :]
-        b = _reverse_tile(bw[pl.ds(rb, SUBLANES), :])
-        w[pl.ds(r, SUBLANES), :] = jnp.where(keep_low, jnp.minimum(a, b),
-                                             jnp.maximum(a, b))
+
+def _half_clean(w, bw, keep_low):
+    """Keep in ``w`` the min (``keep_low``) or the max of ``w[i]`` and
+    ``bw[L-1-i]``, a register block an iteration: block t of ``w`` pairs
+    with block ``nb-1-t`` of ``bw``, reversed."""
+    rows = w.shape[0]
+    nb = half_clean_blocks(rows * LANES)
+    br = rows // nb
+    shape = (br // SUBLANES, SUBLANES, LANES)
+
+    def body(t, carry):
+        r = pl.multiple_of(t * br, br)
+        rb = pl.multiple_of((nb - 1 - t) * br, br)
+        a = w[pl.ds(r, br), :].reshape(shape)
+        b = _reverse(bw[pl.ds(rb, br), :].reshape(shape))
+        w[pl.ds(r, br), :] = jnp.where(keep_low, jnp.minimum(a, b),
+                                       jnp.maximum(a, b)).reshape(br, LANES)
         return carry
 
-    jax.lax.fori_loop(0, tiles, half_clean, 0)
-    merge_stage(w, w.shape[0] * LANES)
+    jax.lax.fori_loop(0, nb, body, 0)
 
 
 def _merge_split_keys(a, b, keep, interpret: bool):

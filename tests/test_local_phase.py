@@ -17,15 +17,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 from jax.sharding import AxisType
 
 from repro.core import (LOCAL_PHASES, Homing, Locale, LocalisationPolicy,
                         exchange_schedule)
 from repro.core.sort import merge_sorted
 from repro.kernels import ops
-from repro.kernels.bitonic_sort import (BLOCK_ROWS, LANES, TILE, block_keys,
-                                        sweep_plan)
+from repro.kernels.bitonic_sort import (BLOCK_ROWS, LANES, SUBLANES, TILE,
+                                        block_keys, from_keys, sweep_plan,
+                                        to_keys)
 from repro.kernels.local_sort import local_sort
+from repro.kernels.merge_split import (HALF_CLEAN_ROWS, _reverse,
+                                       half_clean_blocks)
 from repro.obs import Tracer, set_tracer
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -171,15 +175,79 @@ def test_local_sort_past_one_register_block(name, C, rows):
                                   flip(np.sort(flip(_bits(x)), axis=-1)))
 
 
-def test_merge_split_past_one_register_block():
-    C, rows = 2 * BLOCK, 2
-    a = jnp.sort(_rows("rand_int", C, rows), axis=-1)
-    b = jnp.sort(_rows("dups_int", C, rows), axis=-1)
-    keep = jnp.asarray([True, False])
-    out = np.asarray(ops.merge_split(a, b, keep))
-    for r in range(rows):
-        full = np.asarray(merge_sorted(a[r], b[r]))
-        np.testing.assert_array_equal(out[r], full[:C] if r == 0 else full[C:])
+HALF_CLEAN = HALF_CLEAN_ROWS * LANES     # keys in one half-clean block
+
+
+@jax.jit
+@jax.vmap
+def _merges(a, b):
+    """Row-wise `merge_sorted` of the values, and of the keys (as values)."""
+    return (merge_sorted(a, b),
+            from_keys(merge_sorted(to_keys(a), to_keys(b)), a.dtype))
+
+
+def _sorted_rows(name: str, C: int, rows: int, seed: int):
+    """Sorted rows from numpy, floats in the keys' total order (-0.0 before
+    +0.0): ``tied_zeros`` floats of eight values with both zeros among them,
+    ``dups_int`` int32 of eight values, ``rand_int`` int32 of many."""
+    g = np.random.default_rng([C, rows, seed])
+    if name != "tied_zeros":
+        hi = 10**6 if name == "rand_int" else 4
+        return np.sort(g.integers(-hi, hi, (rows, C)).astype(np.int32), -1)
+    x = (g.integers(-4, 4, (rows, C)) / 2).astype(np.float32)
+    x[:, 1::4] = -0.0
+    flip = lambda k: k ^ ((k >> 31) & BIGI)     # noqa: E731
+    return flip(np.sort(flip(x.view(np.int32)), -1)).view(np.float32)
+
+
+@pytest.mark.parametrize("name, C", [
+    ("tied_zeros", TILE),                # shorter than one half-clean block
+    ("tied_zeros", 1 << 12),
+    ("tied_zeros", HALF_CLEAN),          # 1, 2 and 4 half-clean blocks
+    ("tied_zeros", 2 * HALF_CLEAN),
+    ("tied_zeros", 4 * HALF_CLEAN),
+    ("dups_int", 2 * BLOCK)])            # two network blocks: a row sweep
+def test_merge_split_past_one_register_block(name, C):
+    rows = 2
+    a = _sorted_rows("rand_int" if name == "dups_int" else name, C, rows, 0)
+    b = _sorted_rows(name, C, rows, 1)
+    full, keys = (np.asarray(m) for m in _merges(a, b))
+    for keep in ([True, False], [False, True]):
+        out = np.asarray(ops.merge_split(a, b, jnp.asarray(keep)))
+        for r, lo in enumerate(keep):
+            half = slice(None, C) if lo else slice(C, None)
+            np.testing.assert_array_equal(out[r], full[r, half])
+            # bit for bit: the merge of the keys, -0.0 before +0.0
+            np.testing.assert_array_equal(_bits(out[r]), _bits(keys[r, half]))
+
+
+@pytest.mark.parametrize("n", (1, 2, 8, HALF_CLEAN_ROWS // SUBLANES))
+def test_blocked_reverse_is_numpy_reverse(n):
+    """`_reverse` of an (n, 8, 128) block: the flattened keys back to
+    front, the register order by renaming and every tile in registers."""
+    shape = (n * SUBLANES, LANES)
+    x = jax.random.permutation(jax.random.key(n),
+                               jnp.arange(n * TILE, dtype=jnp.int32))
+
+    def kernel(x_ref, o_ref):
+        v = x_ref[...].reshape(n, SUBLANES, LANES)
+        o_ref[...] = _reverse(v).reshape(shape)
+
+    got = pl.pallas_call(kernel, out_shape=jax.ShapeDtypeStruct(shape,
+                                                                jnp.int32),
+                         interpret=True)(x.reshape(shape))
+    np.testing.assert_array_equal(np.asarray(got).reshape(-1),
+                                  np.asarray(x)[::-1])
+
+
+@pytest.mark.parametrize("L, blocks", [
+    (TILE, 1), (1 << 12, 1), (HALF_CLEAN, 1), (2 * HALF_CLEAN, 2),
+    (4 * HALF_CLEAN, 4),
+    (1 << 23, 256)])                     # a run of the 100M-key sort
+def test_half_clean_blocks(L, blocks):
+    """The half-clean pass makes one loop iteration per register block of
+    ``HALF_CLEAN_ROWS`` rows, or one for a row shorter than a block."""
+    assert half_clean_blocks(L) == blocks
 
 
 @pytest.mark.parametrize("L", (TILE, 1 << 15, 1 << 23))

@@ -20,7 +20,8 @@ import pytest
 import repro.core.engine as engine
 from repro.analysis.vmem import pallas_footprints
 from repro.core import Locale, LocalisationPolicy, exchange_schedule
-from repro.kernels.bitonic_sort import TILE
+from repro.kernels.bitonic_sort import LANES, TILE
+from repro.kernels.merge_split import HALF_CLEAN_ROWS, run_merge
 from repro.obs import Tracer, set_tracer
 from repro.obs.reconcile import check_engine, check_schema
 
@@ -98,6 +99,23 @@ def test_past_vmem_ties_extremes_and_floats(name, runs):
     got = _sort(x.copy())
     np.testing.assert_array_equal(got, np.sort(x))
     np.testing.assert_array_equal(_bits(got), _expect_bits(x))
+
+
+def test_run_merge_past_one_half_clean_block():
+    """Rows of two half-clean register blocks, each row's partner below or
+    above it under either keep flag (the last run all sentinels): every row
+    is bit for bit the kept half of the merge of its pair."""
+    L = 2 * HALF_CLEAN_ROWS * LANES
+    x = np.sort(np.stack([_keys("uniform", L, 0), _keys("ties", L, 1),
+                          _keys("uniform", L, 2), _keys("all_max", L)]),
+                axis=-1)
+    partner = np.array([1, 0, 3, 2])     # below, above, below, above
+    keep = np.array([True, False, False, True])
+    got = np.asarray(run_merge(jnp.asarray(x), partner, keep))
+    for r in range(4):
+        pair = np.sort(np.concatenate([x[r], x[partner[r]]]))
+        np.testing.assert_array_equal(got[r], pair[:L] if keep[r] else pair[L:],
+                                      err_msg=f"row {r}")
 
 
 @pytest.mark.parametrize("runs, p, substages",
