@@ -6,7 +6,7 @@ lowers it for a representative granular input, and runs every rule over
 the resulting artifacts (optimized SPMD HLO + jaxpr + the engine's
 exchange-network descriptor).  `check_decode` does the same for the
 serving decode step.  Nothing is ever *executed* — the whole analysis is
-static, so locality bugs surface at compile time, not in BENCH diffs.
+static, so locality bugs surface at compile time, not in benchmark diffs.
 
 `rules` filters which rules run (None/'all' = every rule); R1/R2 need HLO
 facts, R3/R5/R7/R8 the jaxpr, R6 the (policy, mesh-slice) the shard_map

@@ -2,8 +2,9 @@
 
 Every assigned architecture is a frozen `ArchConfig` built from the exact
 figures in the assignment brief. `reduce_config` derives the tiny smoke-test
-variant of the same family; the full configs are exercised only through the
-dry-run (ShapeDtypeStruct, no allocation).
+variant of the same family; the full configs run through `launch/train.py`
+and `launch/serve.py`, and `tests/test_models.py` pins their parameter
+counts.
 """
 from __future__ import annotations
 

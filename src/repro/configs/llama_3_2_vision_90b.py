@@ -1,5 +1,5 @@
 """llama-3.2-vision-90b [vlm] — cross-attn image layers; vision tower is a
-STUB (input_specs provides precomputed patch embeddings).
+STUB (the batch carries precomputed patch embeddings).
 [hf:meta-llama/Llama-3.2-11B-Vision; unverified]
 
 100 layers total = 80 self-attention + 20 cross-attention (1 per 5).

@@ -1,9 +1,9 @@
 """musicgen-medium [audio] — decoder-only over EnCodec tokens; the EnCodec
-frontend is a STUB (input_specs provides precomputed frame embeddings).
+frontend is a STUB (the batch carries precomputed frame embeddings).
 [arXiv:2306.05284; hf]
 
 24 heads do not divide the 16-way model axis -> attention runs
-head-replicated; TP applies to the FFN + vocab head (see DESIGN.md §4).
+head-replicated; TP applies to the FFN + vocab head.
 """
 from repro.configs.base import ArchConfig, ParallelConfig, register
 

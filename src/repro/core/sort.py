@@ -66,7 +66,7 @@ def sort_entry(jitted, granule: int):
     arrive summed across it), so the traced fn must only ever see
     already-granular inputs; its internal `pad_to_multiple` then no-ops.
     The sentinel tail is stripped eagerly after the call.  `.lower` (used by
-    the HLO structure benchmarks/tests) passes through to the jitted fn.
+    the HLO structure tests) passes through to the jitted fn.
     """
     @functools.wraps(getattr(jitted, "__wrapped__", jitted))
     def call(x, *args, **kw):

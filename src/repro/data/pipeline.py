@@ -12,9 +12,8 @@ Host-side generation is *striped* to match: every leaf of the batch
 per-row content function, and under ``striped=True`` (the default) each
 device's callback materialises only the rows that device owns — the full
 ``(B, S[, D])`` array is never built on the host.  ``striped=False`` keeps
-the old build-everything-then-place path as the bit-exact oracle;
-``benchmarks/bench_striping.py --pipeline`` times the two against each
-other (the ROADMAP's striping acceptance benchmark).
+the old build-everything-then-place path as the bit-exact oracle that
+`tests/test_substrate.py` checks the striped path against.
 
 Determinism: batch content is a pure function of (seed, step, element row),
 so a restart replays exactly the same batches — the property checkpoint
@@ -135,7 +134,7 @@ class SyntheticLM:
     def _host_batch(self, step: int, locale: Locale) -> dict:
         """The pre-striping oracle: build every full array on the host, then
         place it.  Same per-row content functions, so `striped=True` must
-        reproduce it bit-exactly; kept for the acceptance benchmark."""
+        reproduce it bit-exactly; kept as the tests' oracle."""
         B, S, V = self.global_batch, self.seq_len, self.cfg.vocab_size
 
         def place(a: np.ndarray):

@@ -64,7 +64,7 @@ def banded_swa_attention(q, k, v, *, window: int, plan: MeshPlan = None,
     The paper's locality discipline applied to the sequence dim: query block
     i touches only KV blocks {i-1, i} (block length == window), so 32k-token
     SWA prefill does S/(2W) = 4x less score work and movement than scanning
-    every KV chunk (mixtral iter1, EXPERIMENTS.md §Perf).
+    every KV chunk.
     q: (B, Sq, KV, Gq, hd); k, v: (B, Sq, KV, hd); Sq % window == 0.
     """
     B, Sq, KV, Gq, hd = q.shape
@@ -178,7 +178,7 @@ def chunked_attention(q, k, v, *, q_positions, kv_positions, causal: bool,
         acc_new = acc * corr[..., None] + pv
         # pin the online-softmax state to the head sharding: without this the
         # partitioner is free to re-gather the (B,KV,Gq,Sq,*) state across the
-        # model axis on every KV chunk (glm4 iter1, EXPERIMENTS.md §Perf)
+        # model axis on every KV chunk
         m_new = ws(m_new, plan, b_ax, kv_ax, gq_ax, seq_ax)
         l_new = ws(l_new, plan, b_ax, kv_ax, gq_ax, seq_ax)
         acc_new = ws(acc_new, plan, b_ax, kv_ax, gq_ax, seq_ax, None)

@@ -5,7 +5,8 @@ Expert placement is the paper's homing decision at pod scale: each expert is
 activation to its expert's home (all-to-all), compute runs local to the
 expert shard, and the combine einsum brings results back. When the expert
 count does not divide the model axis (mixtral: 8 experts, 16-way axis) the
-experts are replicated and the FFN dim is TP-sharded instead (see DESIGN.md).
+experts are replicated and the FFN dim is TP-sharded instead
+(`sharding/partition.py:make_plan` picks the expert axis).
 """
 from __future__ import annotations
 

@@ -1,7 +1,7 @@
 """Pure step functions: train / prefill / decode.
 
-These close over (model, plan, optimizer) and are what `launch/dryrun.py`
-lowers and `runtime/trainer.py` executes.
+These close over (model, plan, optimizer); `runtime/trainer.py` executes
+the train step.
 """
 from __future__ import annotations
 
@@ -26,7 +26,7 @@ def _constrain_like_params(tree, params, plan: MeshPlan, cfg):
     Without this the microbatch grad accumulator has no layout and XLA is
     free to replicate f32 gradients across the data axes (for FSDP'd params
     that is dp_size x the memory and an all-reduce instead of a
-    reduce-scatter). Perf iteration #1 in EXPERIMENTS.md §Perf.
+    reduce-scatter).
     """
     if plan is None or plan.mesh is None:
         return tree

@@ -2,7 +2,7 @@
 
 The paper's argument is about *where bytes move* — per-tile caches vs the
 mesh networks — yet until this package the runtime could only show that
-after the fact, as BENCH diffs.  `repro.obs` makes the traffic visible
+after the fact, as benchmark diffs.  `repro.obs` makes the traffic visible
 live and, in the PR 6-9 spirit, *checked*:
 
 ``repro.obs.tracelog``
@@ -17,9 +17,9 @@ live and, in the PR 6-9 spirit, *checked*:
     pages/refs/hit-rate, relayout + inter/intra-pod bytes, wave
     utilisation and per-wave wait histograms, snapshotted at wave
     boundaries — and the ONE rendering path (`summarise` ->
-    `format_summary` / `bench_rows` / JSON) every consumer shares:
-    ``launch/serve`` exit summaries, ``bench_serve`` CSV rows and the
-    trace's final ``sched.summary`` event are the same dict.
+    `format_summary` / JSON) every consumer shares: ``launch/serve``
+    exit summaries and the trace's final ``sched.summary`` event are
+    the same dict.
 
 ``repro.obs.reconcile``
     The offline trace validator: replays a trace and *proves* the counter
@@ -28,7 +28,7 @@ live and, in the PR 6-9 spirit, *checked*:
     off-home placement has a matching charge; the engine's stamped
     per-level bytes == a fresh `exchange_schedule`).  A regression in the
     observability layer itself shows up as a trace-identity failure, not
-    a slower BENCH row.  CLI: ``repro.launch.tracelog --validate``.
+    a slower benchmark row.  CLI: ``repro.launch.tracelog --validate``.
 
 Import note: this package must stay import-light (the runtime hot paths
 import it), so ``reconcile`` — which pulls in `repro.core.engine` — is a

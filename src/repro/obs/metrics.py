@@ -11,14 +11,12 @@ canonical summary dict every consumer renders from:
 
 * ``format_summary(summary)``  — the human exit report
   (`launch/serve.py`, `Scheduler.format_summary`),
-* ``bench_rows(name, summary, wall_us)`` — the ``name,us,derived`` CSV
-  rows `benchmarks/bench_serve.py` prints (and `compare.py` gates),
 * the ``sched.summary`` trace event (`Scheduler.emit_summary`) that
   `repro.obs.reconcile` checks every traced counter against.
 
-Because all three render the same dict, a stat can't drift between the
-launcher's print, the bench baseline and the trace — the reconciliation
-identities would catch it.
+Because both render the same dict, a stat can't drift between the
+launcher's print and the trace — the reconciliation identities would
+catch it.
 """
 from __future__ import annotations
 
@@ -190,36 +188,3 @@ def format_summary(summary: Dict[str, Any]) -> str:
             f"pool_pages={summary['pool_pages']} "
             f"live_refs={summary['pool_live_refs']}")
     return "\n".join(lines)
-
-
-def bench_rows(name: str, summary: Dict[str, Any],
-               wall_us: float) -> List[str]:
-    """The ``name,us_per_call,derived`` CSV rows `bench_serve` prints.
-
-    Field names and formats are pinned by the committed BENCH_serve.json
-    baselines and `compare.py`'s derived-field gates (``tok_s`` /
-    ``rows_saved`` on the ``_prefix`` family, ``p50``/``p99`` on the
-    ``_wait`` family) — rendering them here is what makes the bench rows,
-    the launcher summary and the trace summary the same numbers.
-    """
-    tokens = summary["tokens_out"]
-    tok_s = tokens / (wall_us / 1e6) if wall_us else 0.0
-    return [
-        f"{name},{wall_us / max(1, tokens):.0f},"
-        f"tok_s={tok_s:.0f};served={summary['served']};"
-        f"tokens={tokens};steps={summary['steps']:.0f};"
-        f"waves={summary['waves']};"
-        f"util={summary['utilisation']:.3f};"
-        f"pages={summary['pages_attached']};"
-        f"hits_full={summary['prefix_hits_full']};"
-        f"hits_part={summary['prefix_hits_partial']};"
-        f"rows_saved={summary['prefill_rows_saved']:.1f}",
-        f"{name}_wait,,"
-        f"p50={summary['wait_p50']:.1f};p99={summary['wait_p99']:.1f}",
-        f"{name}_relayout,,"
-        f"total={summary['relayout_bytes']};"
-        f"inter_pod={summary['inter_pod_bytes']};"
-        f"intra_pod={summary['intra_pod_bytes']};"
-        f"events={summary['relayout_events']};"
-        f"affinity_hits={summary['affinity_hits']}",
-    ]

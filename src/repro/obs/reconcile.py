@@ -2,12 +2,12 @@
 
 A trace is evidence, not truth — this module makes it truth by replaying
 the per-event records and checking them against the final ``sched.summary``
-(the same canonical dict the launcher prints and the bench rows render)
-and against the *analytic* cost model (`repro.core.engine.exchange_schedule`
-recomputed from each ``engine.sort`` span's stamped arguments).  A bug in
+(the same canonical dict the launcher prints) and against the *analytic*
+cost model (`repro.core.engine.exchange_schedule` recomputed from each
+``engine.sort`` span's stamped arguments).  A bug in
 the instrumentation, the scheduler's charge accounting, or the metrics
 registry shows up as an identity failure here, not as a quietly wrong
-BENCH row.
+benchmark row.
 
 The identities (per reconciliation segment — a trace may hold several
 serving runs; each ``sched.summary`` event closes one):

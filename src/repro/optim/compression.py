@@ -1,6 +1,6 @@
 """Gradient compression: int8 block quantisation + error feedback.
 
-Rationale (DESIGN.md §5): the cross-pod data-parallel axis is the slow link
+Rationale: the cross-pod data-parallel axis is the slow link
 (DCN/ICI-limited); quantising gradient traffic to int8 cuts its collective
 bytes 4x. `compressed_psum` is the shard_map building block (all-gather of
 int8 payloads + local dequant-reduce — wire format is genuinely 1 byte per

@@ -930,8 +930,8 @@ class Scheduler:
 
     def summary(self) -> Dict:
         """The canonical summary dict (see `repro.obs.metrics.summarise`).
-        One rendering path: the launcher's human report, bench_serve's CSV
-        rows and the trace's ``sched.summary`` event all read this dict."""
+        One rendering path: the launcher's human report and the trace's
+        ``sched.summary`` event both read this dict."""
         return obs_metrics.summarise(self)
 
     def format_summary(self) -> str:

@@ -93,7 +93,9 @@ def make_plan(mesh: Optional[Mesh], cfg: ArchConfig, shape: ShapeSpec) -> MeshPl
     fsdp_axes = dp if (cfg.parallel.fsdp and _div(cfg.d_model, dp_n)) else None
     zero1_axes = dp if (cfg.parallel.zero1 and _div(cfg.d_model, dp_n)) else None
 
-    # decode-time KV cache layout (see DESIGN.md §5)
+    # decode-time KV cache layout: a cache of 8k+ positions shards its
+    # sequence over "data" when the batch cannot shard (context parallel),
+    # else over the model axis when the KV heads cannot
     cache_len = min(shape.seq_len, cfg.sliding_window) if cfg.sliding_window else shape.seq_len
     cache_seq_axis = None
     if shape.kind == "decode":
@@ -180,38 +182,6 @@ def batch_specs(batch_struct, plan: MeshPlan):
         b = plan.batch_axes
         return P(b, *([None] * (len(leaf.shape) - 1)))
     return jax.tree_util.tree_map_with_path(spec, batch_struct)
-
-
-def cache_specs(cache_struct, plan: MeshPlan, cfg: ArchConfig):
-    """Decode-cache layout (see DESIGN.md §5)."""
-    b = plan.batch_axes
-    tp = plan.tp
-    kv = plan.kv_axis
-    cseq = plan.cache_seq_axis
-    hs_ax = tp if (cfg.ssm_nheads and cfg.ssm_nheads % plan.tp_size == 0) else None
-    di_ax = tp if (cfg.d_inner and cfg.d_inner % max(plan.tp_size, 1) == 0) else None
-
-    def spec(path, leaf):
-        keys = [getattr(k, "key", str(k)) for k in path]
-        name = keys[-1]
-        nd = len(leaf.shape)
-        if name in ("k", "v"):
-            is_cross = leaf.shape[2] == cfg.num_image_tokens and cfg.num_image_tokens
-            s_ax = None if is_cross else cseq
-            return P(None, b, s_ax, kv, None)
-        if name == "kpos":
-            # self-attn kpos is per-row (nsb, B, Sc) since the per-slot
-            # position clocks; cross kpos stays shared (nsb, n_img)
-            return P(None, b, cseq) if nd == 3 else P(None, cseq)
-        if name == "ssm":
-            return P(None, b, hs_ax, None, None)
-        if name == "conv_x":
-            return P(None, b, None, di_ax)
-        if name == "conv_bc":
-            return P(None, b, None, None)
-        return P(*([None] * nd))
-
-    return jax.tree_util.tree_map_with_path(spec, cache_struct)
 
 
 def opt_specs(params_struct, plan: MeshPlan, cfg: ArchConfig):

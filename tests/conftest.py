@@ -1,7 +1,7 @@
 import os
 
-# smoke tests and benches must see exactly 1 device (the dry-run, and only
-# the dry-run, creates the 512-device placeholder platform in a subprocess).
+# tests see exactly 1 CPU device; the multi-device tests start a subprocess
+# with XLA_FLAGS=--xla_force_host_platform_device_count=N of their own.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax  # noqa: E402

@@ -290,48 +290,3 @@ print("API_8DEV_OK")
                        text=True, env={**os.environ, "PYTHONPATH": "src"},
                        cwd=ROOT, timeout=900)
     assert "API_8DEV_OK" in r.stdout, r.stdout + r.stderr
-
-
-# ---------------------------------------------------------------------------
-# benchmark surface: --smoke keeps every entry point alive
-# ---------------------------------------------------------------------------
-def test_benchmarks_smoke_emits_json(tmp_path):
-    r = subprocess.run(
-        [sys.executable, "-m", "benchmarks.run", "--smoke", "--skip-local",
-         "--out", str(tmp_path)],
-        capture_output=True, text=True, cwd=ROOT, timeout=420,
-        env={**os.environ, "PYTHONPATH": "src"})
-    assert r.returncode == 0, r.stdout + r.stderr
-    import json
-    import re
-    sort = json.load(open(tmp_path / "BENCH_sort.json"))
-    micro = json.load(open(tmp_path / "BENCH_microbench.json"))
-    assert sort and micro, (sort, micro)
-    timed = [rec for rec in sort if rec["us"] is not None]
-    assert timed and all(rec["us"] > 0 for rec in timed)
-    assert {rec["backend"] for rec in sort} >= {"constraint"}
-    assert any(rec["n"] for rec in sort)
-    # the --pods grid ran too: BENCH_engine.json carries the per-policy
-    # inter/intra-pod exchange-byte totals, and the hierarchical policy
-    # moves strictly fewer inter-pod bytes than the flat non-localised path
-    engine = json.load(open(tmp_path / "BENCH_engine.json"))
-
-    def inter_total(prefix):
-        recs = [r for r in engine
-                if prefix in r["name"] and "inter_total=" in r["derived"]]
-        assert len(recs) == 1, (prefix, engine)
-        return int(re.search(r"inter_total=(\d+)", recs[0]["derived"]).group(1))
-
-    assert inter_total("_hier.") < inter_total("_nonloc-")
-    # the serving scheduler ran too: both policies timed, and the recorded
-    # acceptance facts hold (bit-identical decode, homed strictly fewer
-    # cross-home relayout bytes, homed no more deterministic steps)
-    serve = json.load(open(tmp_path / "BENCH_serve.json"))
-    assert {r["name"].split("_")[1] for r in serve
-            if r["us"] is not None} >= {"fifo", "homed"}
-    checks = [r for r in serve if r["name"].startswith("serve_check_")]
-    assert checks, serve
-    for rec in checks:
-        assert "bit_identical=True" in rec["derived"], rec
-        assert "relayout_homed_lt_fifo=True" in rec["derived"], rec
-        assert "steps_homed_le_fifo=True" in rec["derived"], rec
